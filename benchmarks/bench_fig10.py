@@ -13,6 +13,7 @@ from repro.core.binary import MultiTargetBinary
 from repro.core.function import FunctionRegistry, MigratableFunction
 from repro.core.targets import TargetKind
 from repro.kernels import ops, ref
+from repro.launch import compile_cache
 
 
 def _host_knn(test, train, labels):
@@ -65,4 +66,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 from benchmarks.common import emit
 from repro.kernels import ops, ref
 from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.launch import compile_cache
 
 
 def _time(fn, *args, reps: int = 3) -> float:
@@ -105,4 +106,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

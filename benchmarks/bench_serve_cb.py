@@ -114,11 +114,13 @@ from repro.core.function import FunctionRegistry
 from repro.core.policy import Decision, PinAccel
 from repro.core.runtime import XarTrekRuntime
 from repro.core.targets import TargetKind
+from repro.launch import compile_cache
 from repro.models.attention import paged_kv_block_bytes
 from repro.serve import (ClusterFrontEnd, ContinuousBatchingEngine,
                          GenerationRequest, ProcClusterFrontEnd,
                          SamplingParams, ServeEngine)
 from repro.serve import spec as spec_lib
+from repro.serve.proc import require_cpu_backend
 from repro.serve.scheduler import RequestQueue, poisson_arrivals
 
 MAX_SLOTS = 4
@@ -333,6 +335,9 @@ def main(argv=None) -> int:
     # parse_known_args: benchmarks.run invokes main() under ITS argv
     # (--only ...); unknown flags must not crash the module
     args, _ = ap.parse_known_args(argv)
+    if args.cluster_proc:
+        # refuse before the in-process legs below take the chip
+        require_cpu_backend()
 
     cfg = dataclasses.replace(reduced(ARCHS["smollm-135m"]), dtype="float32")
     sync = ServeEngine(cfg, seed=args.seed)
@@ -956,4 +961,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     sys.exit(main())

@@ -27,6 +27,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     wanted = [w for w in args.only.split(",") if w]
     print("name,us_per_call,derived")
     failures = []

@@ -39,6 +39,7 @@ from repro.core.function import FunctionRegistry
 from repro.core.policy import PinAccel, PinHost, XarTrekHeuristic
 from repro.core.runtime import XarTrekRuntime
 from repro.core.targets import TargetKind
+from repro.launch import compile_cache
 from repro.serve import (ContinuousBatchingEngine, GenerationRequest,
                          SamplingParams)
 from repro.serve.scheduler import poisson_arrivals
@@ -63,6 +64,7 @@ def main() -> None:
                     help="pin every step to one build, or let the "
                          "scheduler migrate (auto)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = reduced(ARCHS["smollm-135m"])
     policy = {"host": PinHost(), "accel": PinAccel(),

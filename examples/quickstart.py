@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCHS, reduced
 from repro.configs.model_config import ShapeConfig, TrainConfig
+from repro.launch import compile_cache
 from repro.serve.engine import ServeEngine
 from repro.train.trainer import Trainer
 
@@ -21,6 +22,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = reduced(ARCHS["smollm-135m"])
     shape = ShapeConfig("quickstart", seq_len=64, global_batch=8, kind="train")

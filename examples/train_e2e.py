@@ -14,6 +14,7 @@ import time
 
 from repro.configs import ARCHS, reduced as make_reduced
 from repro.configs.model_config import ShapeConfig, TrainConfig
+from repro.launch import compile_cache
 from repro.train.trainer import FailureInjector, Trainer
 
 
@@ -29,6 +30,7 @@ def main() -> None:
                     help="inject a failure at this step (0=off)")
     ap.add_argument("--reduced", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = ARCHS[args.arch]
     if args.reduced:

@@ -14,8 +14,8 @@ from collections import OrderedDict
 from typing import Any, Optional
 
 import jax
+from jax.sharding import SingleDeviceSharding
 
-from repro.parallel.compat import use_mesh
 from repro.core.function import MigratableFunction
 from repro.core.policy import ewma
 from repro.core.targets import TargetKind
@@ -43,6 +43,27 @@ def shape_key(args: tuple) -> tuple:
     return (treedef, tuple(
         (l.shape, l.dtype) if hasattr(l, "shape") else (type(l), l)
         for l in leaves))
+
+
+def committed_device(tree) -> Optional[jax.Device]:
+    """The one device every committed array of ``tree`` lives on, or None
+    (nothing committed, or spread over a mesh)."""
+    devices = {d for x in jax.tree.leaves(tree)
+               if isinstance(x, jax.Array) and x.committed
+               for d in x.devices()}
+    return devices.pop() if len(devices) == 1 else None
+
+
+def abstract_args(args: tuple) -> tuple:
+    """Shape-only specs of a call's args for an AOT compile.  Args
+    committed to one device (an engine replica pinned to its chip) pin
+    the specs, and so the executable, to that device; otherwise the
+    compile targets the default device."""
+    dev = committed_device(args)
+    sharding = None if dev is None else SingleDeviceSharding(dev)
+    return tuple(jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        a) for a in args)
 
 
 @dataclasses.dataclass
@@ -97,7 +118,7 @@ class MultiTargetBinary:
         t0 = time.perf_counter()
         jitted = self._jit(kind)
         if self.mesh is not None:
-            with use_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 lowered = jitted.lower(*specs)
                 compiled = lowered.compile()
         else:
@@ -143,10 +164,7 @@ class MultiTargetBinary:
             self.shape_stats["hits"] += 1
             return cv
         self.shape_stats["misses"] += 1
-        specs = tuple(jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), a)
-            for a in args)
-        cv = lru[key] = self._compile_specs(kind, specs)
+        cv = lru[key] = self._compile_specs(kind, abstract_args(args))
         while len(lru) > self.max_shape_buckets:
             lru.popitem(last=False)
             self.shape_stats["evictions"] += 1

@@ -6,7 +6,10 @@ TPU analogue keeps a bounded bank of compiled ACCEL (Pallas-variant)
 executables; loading a non-resident one is an asynchronous compile +
 warm-up on a background thread.  Algorithm 2's "No HW Kernel" branches
 consult ``is_resident``; the latency-hiding behaviour (keep running on
-a CPU target until the load completes) falls out naturally.
+a CPU target until the load completes) falls out naturally.  A load
+that FAILS is recorded, never silently dropped: ``load_sync``,
+``load_async`` and ``raise_if_failed`` re-raise it, so a broken ACCEL
+build cannot turn into HOST service that looks healthy.
 
 ``partition`` reproduces the XCLBIN-partitioning step: greedy grouping
 of kernels into images under a per-image area budget.
@@ -43,6 +46,7 @@ class KernelBank:
         self.clock = clock
         self._resident: dict[str, BankEntry] = {}
         self._loading: dict[str, threading.Thread] = {}
+        self._failed: dict[str, BaseException] = {}
         self._lock = threading.Lock()
         self.stats = {"loads": 0, "evictions": 0, "hits": 0, "misses": 0}
 
@@ -71,8 +75,18 @@ class KernelBank:
             return sorted(self._resident)
 
     # ------------------------------------------------------------ loading
+    def raise_if_failed(self, name: str) -> None:
+        """Re-raise the recorded error of a failed load of ``name``."""
+        with self._lock:
+            err = self._failed.get(name)
+        if err is not None:
+            raise RuntimeError(
+                f"ACCEL build of {name!r} failed to load: {err!r}") from err
+
     def load_async(self, name: str) -> None:
-        """Algorithm 2 l.11/16: 'Reconfigure the FPGA' without blocking."""
+        """Algorithm 2 l.11/16: 'Reconfigure the FPGA' without blocking.
+        Raises if an earlier load of ``name`` failed (no silent retry)."""
+        self.raise_if_failed(name)
         with self._lock:
             if name in self._resident:
                 return
@@ -89,10 +103,17 @@ class KernelBank:
         t = self._loading.get(name)
         if t is not None:
             t.join()
+        self.raise_if_failed(name)
 
     def _do_load(self, name: str) -> None:
         t0 = self.clock()
-        payload = self.load_fn(name)
+        try:
+            payload = self.load_fn(name)
+        except BaseException as e:          # noqa: BLE001 — re-raised on use
+            with self._lock:
+                self._failed[name] = e
+                self._loading.pop(name, None)
+            return
         elapsed = self.clock() - t0
         if elapsed < self.min_load_seconds:
             time.sleep(self.min_load_seconds - elapsed)

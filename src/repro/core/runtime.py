@@ -17,7 +17,7 @@ from typing import Any, Optional
 
 import jax
 
-from repro.core.binary import MultiTargetBinary
+from repro.core.binary import MultiTargetBinary, abstract_args
 from repro.core.function import GLOBAL_REGISTRY, FunctionRegistry
 from repro.core.kernel_bank import KernelBank
 from repro.core.migration import migrate
@@ -90,10 +90,8 @@ class XarTrekRuntime:
         state-carrying callers (serve decode's KV cache) alias in place."""
         fn = self.registry.get(fn_name)
         fn.check_abi(example_args)
-        specs = tuple(jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), a)
-            for a in example_args)
-        self._specs[fn_name] = example_args
+        specs = abstract_args(example_args)
+        self._specs[fn_name] = specs
         binary = MultiTargetBinary(fn, mesh=self.mesh,
                                    donate_argnums=donate_argnums)
         self.binaries[fn_name] = binary
@@ -115,11 +113,8 @@ class XarTrekRuntime:
                 self.bank.load_async(fn_name)   # pre-configuration
 
     def _load_accel(self, fn_name: str):
-        binary = self.binaries[fn_name]
-        specs = tuple(jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), a)
-            for a in self._specs[fn_name])
-        return binary.compile(TargetKind.ACCEL, *specs)
+        return self.binaries[fn_name].compile(TargetKind.ACCEL,
+                                              *self._specs[fn_name])
 
     # -------------------------------------------------------------- call
     def _client(self, app: str):
@@ -154,7 +149,10 @@ class XarTrekRuntime:
         decision = client.before_call()
         kind = decision.target
         if kind == TargetKind.ACCEL and not binary.is_compiled(kind):
-            kind = TargetKind.HOST           # bank raced; fall back
+            # HOST stands in only while the ACCEL load is in flight (the
+            # paper's latency hiding); a load that failed raises here
+            self.bank.raise_if_failed(fn_name)
+            kind = TargetKind.HOST
         if kind not in fn.variants:
             kind = TargetKind.HOST
 

@@ -18,6 +18,13 @@ per-token f32 scale planes through the same block table and
 dequantises in-kernel (one broadcast multiply in VMEM) — the pool is
 never materialised at full precision.
 
+TPU tiling: a block's last two dims must be (8k, 128k) or the whole
+array dims.  The pool's ``(NP, BS, KV, hd)`` ABI would put a 1-of-KV
+slice in the second-minor dim, so the kernels see the pool as
+``(NP, KV, BS, hd)`` — a page block is then one ``(BS, hd)`` plane —
+and a block's scale planes as the whole ``(BS, KV)`` tile, from which
+the kernel selects its kv head's column exactly.
+
 These are the ACCEL variants of the decode hot function (the serve-path
 analogue of the paper's hardware kernel); oracles:
 ``ref.decode_attention_ref`` / ``ref.paged_decode_attention_ref`` /
@@ -36,8 +43,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, idx_ref, o_ref, m_scr, l_scr,
+def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                    acc_scr, *, block_k: int, kv_blocks: int, scale: float):
+    b = pl.program_id(0)
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -49,7 +57,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, idx_ref, o_ref, m_scr, l_scr,
     q = q_ref[0].astype(jnp.float32)                  # (1, hd)
     k = k_ref[0].astype(jnp.float32)                  # (bk, hd)
     v = v_ref[0].astype(jnp.float32)
-    live = idx_ref[0]                                 # attend over [0, live]
+    live = idx_ref[b]                                 # attend over [0, live]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -78,7 +86,8 @@ def gqa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     shared position or (BH,) per-row positions.
 
     Attends over cache positions [0, index].  BH = batch * q-heads with
-    the cache already head-expanded by the ops wrapper.
+    the cache already head-expanded by the ops wrapper.  The per-row
+    positions ride in SMEM as a scalar-prefetch operand.
     """
     BH, _, hd = q.shape
     Smax = k_cache.shape[1]
@@ -89,30 +98,28 @@ def gqa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     kernel = functools.partial(_decode_kernel, block_k=block_k,
                                kv_blocks=nk, scale=scale)
-    if index.ndim:                      # ragged: one live length per row
-        idx = index.astype(jnp.int32)
-        idx_spec = pl.BlockSpec((1,), lambda b, ki: (b,))
-    else:
-        idx = jnp.broadcast_to(index.astype(jnp.int32), (1,))
-        idx_spec = pl.BlockSpec((1,), lambda b, ki: (0,))
-    return pl.pallas_call(
-        kernel,
+    idx = jnp.broadcast_to(index.astype(jnp.int32).reshape(-1), (BH,))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                # per-row live positions
         grid=(BH, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, ki: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda b, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, hd), lambda b, ki: (b, ki, 0)),
-            idx_spec,
+            pl.BlockSpec((1, 1, hd), lambda b, ki, n: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, hd), lambda b, ki, n: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, hd), lambda b, ki, n: (b, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda b, ki: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, 1, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, hd), lambda b, ki, n: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((BH, 1, hd), q.dtype),
         interpret=interpret,
-    )(q, k_cache, v_cache, idx)
+    )(idx, q, k_cache, v_cache)
 
 
 # ------------------------------------------------------------ paged variant
@@ -170,6 +177,25 @@ def _paged_accumulate(q_ref, len_ref, kn_ref, vn_ref, o_ref, m_scr, l_scr,
         o_ref[0, 0] = (acc / jnp.maximum(l_fin, 1e-20)).astype(o_ref.dtype)
 
 
+def head_column(scales, h):
+    """(BS, KV) scale tile -> (BS, 1) column of kv head ``h`` (exact:
+    one selected value plus zeros).  The whole tile is the block because
+    a one-column block of a KV-wide array is not a legal TPU tiling."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 1)
+    return jnp.sum(jnp.where(lane == h, scales, 0.0), axis=1, keepdims=True)
+
+
+def heads_major(pages: jax.Array) -> jax.Array:
+    """(NP, BS, KV, hd) pool -> (NP, KV, BS, hd), the kernels' view: a
+    page block is then one (BS, hd) plane, a legal TPU tiling."""
+    return pages.transpose(0, 2, 1, 3)
+
+
+def scale_tiles(scale: jax.Array) -> jax.Array:
+    """(NP, BS, KV, 1) scale pool -> (NP, BS, KV) f32 tiles."""
+    return scale[..., 0].astype(jnp.float32)
+
+
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
                   o_ref, m_scr, l_scr, acc_scr, *, block_size: int,
                   nbt: int, scale: float):
@@ -182,26 +208,64 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
     del tbl_ref
     _paged_accumulate(q_ref, len_ref, kn_ref, vn_ref, o_ref, m_scr, l_scr,
                       acc_scr,
-                      k_ref[0, :, 0].astype(jnp.float32),
-                      v_ref[0, :, 0].astype(jnp.float32),
+                      k_ref[0, 0].astype(jnp.float32),
+                      v_ref[0, 0].astype(jnp.float32),
                       block_size=block_size, nbt=nbt, scale=scale)
 
 
 def _paged_int8_kernel(tbl_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
                        kn_ref, vn_ref, o_ref, m_scr, l_scr, acc_scr, *,
                        block_size: int, nbt: int, scale: float):
-    """Int8-dequantising variant: the pool blocks arrive as int8 with a
-    parallel (block_size, 1) f32 scale plane streamed through the SAME
-    block-table index map; dequantisation is one broadcast multiply in
-    VMEM, then the shared online-softmax body runs unchanged.  The
-    current token's ``kn``/``vn`` stay full precision (not yet pooled).
+    """Int8-dequantising variant: the pool blocks arrive as int8 with the
+    block's (block_size, KV) f32 scale tile streamed through the SAME
+    block-table index map; dequantisation is one broadcast multiply by
+    this head's scale column in VMEM, then the shared online-softmax
+    body runs unchanged.  The current token's ``kn``/``vn`` stay full
+    precision (not yet pooled).
     """
     del tbl_ref
+    h = pl.program_id(1)
     _paged_accumulate(q_ref, len_ref, kn_ref, vn_ref, o_ref, m_scr, l_scr,
                       acc_scr,
-                      k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0],
-                      v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0],
+                      k_ref[0, 0].astype(jnp.float32)
+                      * head_column(ks_ref[0], h),
+                      v_ref[0, 0].astype(jnp.float32)
+                      * head_column(vs_ref[0], h),
                       block_size=block_size, nbt=nbt, scale=scale)
+
+
+def _paged_call(kernel, extra_specs, q, tables, lengths, *operands,
+                interpret: bool):
+    """Shared ``pallas_call`` of the paged decode kernels: grid
+    ``(B, KV, NBT)``; ``operands`` are the heads-major page planes (and
+    scale tiles, with ``extra_specs`` their block specs) followed by the
+    current token's k/v."""
+    B, KV, G, hd = q.shape
+    block_size = operands[0].shape[2]
+    page_spec = pl.BlockSpec((1, 1, block_size, hd),
+                             lambda b, h, j, t, n: (t[b, j], h, 0, 0))
+    tok_spec = pl.BlockSpec((1, 1, 1, hd), lambda b, h, j, t, n: (b, h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                # tables, lengths
+        grid=(B, KV, tables.shape[1]),
+        in_specs=[
+            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, t, n: (b, h, 0, 0)),
+            *extra_specs(page_spec), tok_spec, tok_spec,
+        ],
+        out_specs=pl.BlockSpec((1, 1, G, hd),
+                               lambda b, h, j, t, n: (b, h, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, hd), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        interpret=interpret,
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, *operands)
 
 
 def paged_gqa_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -217,40 +281,12 @@ def paged_gqa_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     current token.  Rows with length 0 reduce to softmax over the new
     token alone (out = v_new), so inactive serve rows are well-defined.
     """
-    B, KV, G, hd = q.shape
-    block_size = k_pages.shape[1]
-    nbt = tables.shape[1]
-    scale = 1.0 / np.sqrt(hd)
-
-    kernel = functools.partial(_paged_kernel, block_size=block_size,
-                               nbt=nbt, scale=scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                # tables, lengths
-        grid=(B, KV, nbt),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, t, n: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, hd),
-                         lambda b, h, j, t, n: (t[b, j], 0, h, 0)),
-            pl.BlockSpec((1, block_size, 1, hd),
-                         lambda b, h, j, t, n: (t[b, j], 0, h, 0)),
-            pl.BlockSpec((1, 1, 1, hd), lambda b, h, j, t, n: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, hd), lambda b, h, j, t, n: (b, h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, j, t, n: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, v_pages, k_new, v_new)
+    kernel = functools.partial(_paged_kernel, block_size=k_pages.shape[1],
+                               nbt=tables.shape[1],
+                               scale=1.0 / np.sqrt(q.shape[-1]))
+    return _paged_call(kernel, lambda page: (page, page), q, tables, lengths,
+                       heads_major(k_pages), heads_major(v_pages),
+                       k_new, v_new, interpret=interpret)
 
 
 def paged_gqa_decode_int8(q: jax.Array, k_pages: jax.Array,
@@ -265,43 +301,19 @@ def paged_gqa_decode_int8(q: jax.Array, k_pages: jax.Array,
     (NP, BS, KV, 1) f32 — symmetric per-(token, kv-head) scales written
     alongside each quantised token.  The scale planes ride the SAME
     scalar-prefetched block table as the int8 blocks, so each grid step
-    DMAs one (BS, hd) int8 plane plus its (BS, 1) scales and
+    DMAs one (BS, hd) int8 plane plus its block's (BS, KV) scales and
     dequantises in VMEM — no materialised f32 pool anywhere.  q and the
     current token's k_new/v_new stay full precision.
     """
-    B, KV, G, hd = q.shape
-    block_size = k_pages.shape[1]
-    nbt = tables.shape[1]
-    scale = 1.0 / np.sqrt(hd)
-
-    kernel = functools.partial(_paged_int8_kernel, block_size=block_size,
-                               nbt=nbt, scale=scale)
-    page_spec = pl.BlockSpec((1, block_size, 1, hd),
-                             lambda b, h, j, t, n: (t[b, j], 0, h, 0))
-    scale_spec = pl.BlockSpec((1, block_size, 1, 1),
-                              lambda b, h, j, t, n: (t[b, j], 0, h, 0))
-    tok_spec = pl.BlockSpec((1, 1, 1, hd), lambda b, h, j, t, n: (b, h, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                # tables, lengths
-        grid=(B, KV, nbt),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j, t, n: (b, h, 0, 0)),
-            page_spec, scale_spec, page_spec, scale_spec,
-            tok_spec, tok_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda b, h, j, t, n: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, k_scale.astype(jnp.float32),
-      v_pages, v_scale.astype(jnp.float32), k_new, v_new)
+    BS, KV = k_pages.shape[1], k_pages.shape[2]
+    kernel = functools.partial(_paged_int8_kernel, block_size=BS,
+                               nbt=tables.shape[1],
+                               scale=1.0 / np.sqrt(q.shape[-1]))
+    scale_spec = pl.BlockSpec((1, BS, KV),
+                              lambda b, h, j, t, n: (t[b, j], 0, 0))
+    return _paged_call(kernel,
+                       lambda page: (page, scale_spec, page, scale_spec),
+                       q, tables, lengths,
+                       heads_major(k_pages), scale_tiles(k_scale),
+                       heads_major(v_pages), scale_tiles(v_scale),
+                       k_new, v_new, interpret=interpret)

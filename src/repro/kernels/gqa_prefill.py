@@ -41,6 +41,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.gqa_decode import head_column, heads_major, scale_tiles
+
 NEG_INF = -1e30
 
 
@@ -85,8 +87,8 @@ def _prefill_accumulate(q_ref, off_ref, len_ref, kn_ref, vn_ref, o_ref,
     @pl.when(j == nbt - 1)
     def _finish():
         # fold the chunk itself: positions [offset, length), causal
-        kn = kn_ref[0, :, 0].astype(jnp.float32)      # (W, hd)
-        vn = vn_ref[0, :, 0].astype(jnp.float32)
+        kn = kn_ref[0, 0].astype(jnp.float32)         # (W, hd)
+        vn = vn_ref[0, 0].astype(jnp.float32)
         W = kn.shape[0]
         WG = q.shape[0]
         s_cur = jax.lax.dot_general(q, kn, (((1,), (1,)), ((), ())),
@@ -112,8 +114,8 @@ def _prefill_kernel(tbl_ref, off_ref, len_ref, q_ref, k_ref, v_ref, kn_ref,
     del tbl_ref
     _prefill_accumulate(q_ref, off_ref, len_ref, kn_ref, vn_ref, o_ref,
                         m_scr, l_scr, acc_scr,
-                        k_ref[0, :, 0].astype(jnp.float32),
-                        v_ref[0, :, 0].astype(jnp.float32),
+                        k_ref[0, 0].astype(jnp.float32),
+                        v_ref[0, 0].astype(jnp.float32),
                         block_size=block_size, nbt=nbt, scale=scale,
                         group=group)
 
@@ -122,17 +124,59 @@ def _prefill_int8_kernel(tbl_ref, off_ref, len_ref, q_ref, k_ref, ks_ref,
                          v_ref, vs_ref, kn_ref, vn_ref, o_ref, m_scr, l_scr,
                          acc_scr, *, block_size: int, nbt: int, scale: float,
                          group: int):
-    """Int8-dequantising variant: block + (block_size, 1) f32 scale plane
-    stream through the SAME block-table index map; dequantisation is one
-    broadcast multiply in VMEM.  The chunk's ``kn``/``vn`` stay full
-    precision (they are quantised only when scattered into the pool)."""
+    """Int8-dequantising variant: block + its (block_size, KV) f32 scale
+    tile stream through the SAME block-table index map; dequantisation
+    is one broadcast multiply by this head's scale column in VMEM.  The
+    chunk's ``kn``/``vn`` stay full precision (they are quantised only
+    when scattered into the pool)."""
     del tbl_ref
+    h = pl.program_id(1)
     _prefill_accumulate(q_ref, off_ref, len_ref, kn_ref, vn_ref, o_ref,
                         m_scr, l_scr, acc_scr,
-                        k_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0],
-                        v_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0],
+                        k_ref[0, 0].astype(jnp.float32)
+                        * head_column(ks_ref[0], h),
+                        v_ref[0, 0].astype(jnp.float32)
+                        * head_column(vs_ref[0], h),
                         block_size=block_size, nbt=nbt, scale=scale,
                         group=group)
+
+
+def _prefill_call(kernel, extra_specs, q, tables, offsets, lengths,
+                  *operands, interpret: bool):
+    """Shared ``pallas_call`` of the paged prefill kernels: grid
+    ``(B, KV, NBT)``; ``operands`` are the heads-major page planes (and
+    scale tiles, with ``extra_specs`` their block specs) followed by the
+    chunk's heads-major k/v (B, KV, W, hd)."""
+    B, KV, WG, hd = q.shape
+    block_size = operands[0].shape[2]
+    W = operands[-1].shape[2]
+    page_spec = pl.BlockSpec((1, 1, block_size, hd),
+                             lambda b, h, j, t, o, n: (t[b, j], h, 0, 0))
+    tok_spec = pl.BlockSpec((1, 1, W, hd),
+                            lambda b, h, j, t, o, n: (b, h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,                # tables, offsets, lengths
+        grid=(B, KV, tables.shape[1]),
+        in_specs=[
+            pl.BlockSpec((1, 1, WG, hd),
+                         lambda b, h, j, t, o, n: (b, h, 0, 0)),
+            *extra_specs(page_spec), tok_spec, tok_spec,
+        ],
+        out_specs=pl.BlockSpec((1, 1, WG, hd),
+                               lambda b, h, j, t, o, n: (b, h, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((WG, 1), jnp.float32),
+            pltpu.VMEM((WG, 1), jnp.float32),
+            pltpu.VMEM((WG, hd), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, WG, hd), q.dtype),
+        interpret=interpret,
+    )(tables.astype(jnp.int32), offsets.astype(jnp.int32),
+      lengths.astype(jnp.int32), q, *operands)
 
 
 def paged_gqa_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -151,42 +195,14 @@ def paged_gqa_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     self-attention (no pool read survives the final correction), so the
     first chunk of an uncached prompt is well-defined.
     """
-    B, KV, WG, hd = q.shape
-    W = k_new.shape[1]
-    block_size = k_pages.shape[1]
-    nbt = tables.shape[1]
-    scale = 1.0 / np.sqrt(hd)
-
-    kernel = functools.partial(_prefill_kernel, block_size=block_size,
-                               nbt=nbt, scale=scale, group=group)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,                # tables, offsets, lengths
-        grid=(B, KV, nbt),
-        in_specs=[
-            pl.BlockSpec((1, 1, WG, hd),
-                         lambda b, h, j, t, o, n: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, hd),
-                         lambda b, h, j, t, o, n: (t[b, j], 0, h, 0)),
-            pl.BlockSpec((1, block_size, 1, hd),
-                         lambda b, h, j, t, o, n: (t[b, j], 0, h, 0)),
-            pl.BlockSpec((1, W, 1, hd), lambda b, h, j, t, o, n: (b, 0, h, 0)),
-            pl.BlockSpec((1, W, 1, hd), lambda b, h, j, t, o, n: (b, 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, WG, hd),
-                               lambda b, h, j, t, o, n: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((WG, 1), jnp.float32),
-            pltpu.VMEM((WG, 1), jnp.float32),
-            pltpu.VMEM((WG, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, WG, hd), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), offsets.astype(jnp.int32),
-      lengths.astype(jnp.int32), q, k_pages, v_pages, k_new, v_new)
+    kernel = functools.partial(_prefill_kernel, block_size=k_pages.shape[1],
+                               nbt=tables.shape[1],
+                               scale=1.0 / np.sqrt(q.shape[-1]), group=group)
+    return _prefill_call(kernel, lambda page: (page, page), q, tables,
+                         offsets, lengths,
+                         heads_major(k_pages), heads_major(v_pages),
+                         heads_major(k_new), heads_major(v_new),
+                         interpret=interpret)
 
 
 def paged_gqa_prefill_int8(q: jax.Array, k_pages: jax.Array,
@@ -199,45 +215,19 @@ def paged_gqa_prefill_int8(q: jax.Array, k_pages: jax.Array,
 
     k_pages/v_pages: (NP, BS, KV, hd) int8; k_scale/v_scale:
     (NP, BS, KV, 1) f32 symmetric per-(token, kv-head) scales.  Scale
-    planes ride the SAME scalar-prefetched block table as the int8
+    tiles ride the SAME scalar-prefetched block table as the int8
     blocks; q and the chunk's k_new/v_new stay full precision.
     """
-    B, KV, WG, hd = q.shape
-    W = k_new.shape[1]
-    block_size = k_pages.shape[1]
-    nbt = tables.shape[1]
-    scale = 1.0 / np.sqrt(hd)
-
-    kernel = functools.partial(_prefill_int8_kernel, block_size=block_size,
-                               nbt=nbt, scale=scale, group=group)
-    page_spec = pl.BlockSpec((1, block_size, 1, hd),
-                             lambda b, h, j, t, o, n: (t[b, j], 0, h, 0))
-    scale_spec = pl.BlockSpec((1, block_size, 1, 1),
-                              lambda b, h, j, t, o, n: (t[b, j], 0, h, 0))
-    tok_spec = pl.BlockSpec((1, W, 1, hd),
-                            lambda b, h, j, t, o, n: (b, 0, h, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,                # tables, offsets, lengths
-        grid=(B, KV, nbt),
-        in_specs=[
-            pl.BlockSpec((1, 1, WG, hd),
-                         lambda b, h, j, t, o, n: (b, h, 0, 0)),
-            page_spec, scale_spec, page_spec, scale_spec,
-            tok_spec, tok_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, WG, hd),
-                               lambda b, h, j, t, o, n: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((WG, 1), jnp.float32),
-            pltpu.VMEM((WG, 1), jnp.float32),
-            pltpu.VMEM((WG, hd), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, WG, hd), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), offsets.astype(jnp.int32),
-      lengths.astype(jnp.int32), q, k_pages, k_scale.astype(jnp.float32),
-      v_pages, v_scale.astype(jnp.float32), k_new, v_new)
+    BS, KV = k_pages.shape[1], k_pages.shape[2]
+    kernel = functools.partial(_prefill_int8_kernel, block_size=BS,
+                               nbt=tables.shape[1],
+                               scale=1.0 / np.sqrt(q.shape[-1]), group=group)
+    scale_spec = pl.BlockSpec((1, BS, KV),
+                              lambda b, h, j, t, o, n: (t[b, j], 0, 0))
+    return _prefill_call(kernel,
+                         lambda page: (page, scale_spec, page, scale_spec),
+                         q, tables, offsets, lengths,
+                         heads_major(k_pages), scale_tiles(k_scale),
+                         heads_major(v_pages), scale_tiles(v_scale),
+                         heads_major(k_new), heads_major(v_new),
+                         interpret=interpret)

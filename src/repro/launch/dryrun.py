@@ -33,7 +33,7 @@ from repro.launch.hlo_cost import analyze as hlo_analyze
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import RooflineTerms, model_flops
 from repro.models.model import Model, build_model
-from repro.parallel.compat import peak_memory_bytes, use_mesh
+from repro.parallel.mesh import use_mesh
 from repro.train.step import make_train_step, train_step_shardings
 
 ARTIFACT_DIR = os.path.join("artifacts", "dryrun")
@@ -197,7 +197,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             "argument_bytes": mem.argument_size_in_bytes,
             "output_bytes": mem.output_size_in_bytes,
             "temp_bytes": mem.temp_size_in_bytes,
-            "peak_bytes": peak_memory_bytes(mem),
+            "peak_bytes": mem.peak_memory_in_bytes,
             "alias_bytes": mem.alias_size_in_bytes,
         },
         "cost_xla_once": {k: v for k, v in cost.items()

@@ -6,10 +6,10 @@ from __future__ import annotations
 
 import jax
 
-from repro.parallel.compat import make_mesh as _make_mesh
+from repro.parallel.mesh import make_auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)

@@ -25,9 +25,11 @@ def main() -> None:
             f"--xla_force_host_platform_device_count={args.devices}")
 
     import jax
+    from repro.launch import compile_cache
+    compile_cache.enable()
     import jax.numpy as jnp
     from repro.configs import get_arch, reduced
-    from repro.parallel.compat import make_mesh
+    from repro.parallel.mesh import make_auto_mesh
     from repro.serve.engine import ServeEngine
 
     cfg = get_arch(args.arch)
@@ -38,7 +40,7 @@ def main() -> None:
         dims = tuple(int(x) for x in args.mesh.split("x"))
         axes = (("pod", "data", "model") if len(dims) == 3
                 else ("data", "model"))
-        mesh = make_mesh(dims, axes)
+        mesh = make_auto_mesh(dims, axes)
 
     engine = ServeEngine(cfg, mesh=mesh, seed=args.seed)
     key = jax.random.PRNGKey(args.seed)

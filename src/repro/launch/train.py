@@ -43,9 +43,11 @@ def main() -> None:
 
     from repro.configs import get_arch, get_shape, reduced
     from repro.configs.model_config import ShapeConfig, TrainConfig
-    from repro.parallel.compat import make_mesh
+    from repro.launch import compile_cache
+    from repro.parallel.mesh import make_auto_mesh
     from repro.train.trainer import FailureInjector, Trainer
 
+    compile_cache.enable()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -57,7 +59,7 @@ def main() -> None:
         dims = tuple(int(x) for x in args.mesh.split("x"))
         axes = (("pod", "data", "model") if len(dims) == 3
                 else ("data", "model"))
-        mesh = make_mesh(dims, axes)
+        mesh = make_auto_mesh(dims, axes)
 
     tcfg = TrainConfig(microbatches=args.microbatches,
                        learning_rate=args.lr, seed=args.seed)

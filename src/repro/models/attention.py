@@ -180,7 +180,6 @@ def flash_attention_sharded(q, k, v, mesh, *,
     if mesh is None:
         return flash_attention_hybrid(q, k, v)
     from jax.sharding import PartitionSpec as P
-    from repro.models.transformer import shard_map
     from repro.parallel.mesh import MODEL_AXIS, batch_axes
     bdims = batch_axes(mesh)
     B = q.shape[0]
@@ -189,9 +188,9 @@ def flash_attention_sharded(q, k, v, mesh, *,
         dp *= mesh.shape[a]
     bspec = bdims if (bdims and B % dp == 0) else None
     spec = P(bspec, None, MODEL_AXIS, None)
-    f = shard_map(flash_attention_hybrid, mesh=mesh,
-                  in_specs=(spec, spec, spec), out_specs=spec,
-                  check_vma=False)
+    f = jax.shard_map(flash_attention_hybrid, mesh=mesh,
+                      in_specs=(spec, spec, spec), out_specs=spec,
+                      check_vma=False)
     return f(q, k, v)
 
 

@@ -28,6 +28,10 @@ class ParamDef:
     # mask_dims: {dim_index: valid_size} -> zero out the padded tail
     mask_dims: dict[int, int] = dataclasses.field(default_factory=dict)
     dtype: Optional[str] = None     # override model dtype (e.g. fp32 norms)
+    # "scaled" init's fan-in when the contracted input is not the
+    # second-to-last dim (attention projections: (…, d, heads, hd) in,
+    # (…, heads, hd, d) out)
+    fan_in: Optional[int] = None
 
     def spec(self, rules: ShardingRules) -> P:
         return rules.spec(*self.logical)
@@ -41,8 +45,9 @@ def _init_array(key, d: ParamDef, dtype) -> jax.Array:
         x = jnp.ones(d.shape, dt)
     else:
         scale = d.scale
-        if d.init == "scaled":  # 1/sqrt(fan_in) on the second-to-last dim
-            fan_in = d.shape[-2] if len(d.shape) >= 2 else d.shape[-1]
+        if d.init == "scaled":  # 1/sqrt(fan_in), default: 2nd-to-last dim
+            fan_in = d.fan_in or (d.shape[-2] if len(d.shape) >= 2
+                                  else d.shape[-1])
             scale = 1.0 / math.sqrt(fan_in)
         x = (jax.random.normal(key, d.shape, jnp.float32) * scale).astype(dt)
     for dim, valid in d.mask_dims.items():

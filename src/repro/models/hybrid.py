@@ -39,13 +39,15 @@ def hybrid_defs(cfg: ModelConfig, geom: Geometry) -> dict:
     shared = {
         "attn": {
             "wq": ParamDef((d, Hp, hd), ("embed", "heads", "head_dim"),
-                           "scaled", mask_dims={1: H}),
+                           "scaled", mask_dims={1: H}, fan_in=d),
             "wk": ParamDef((d, KV, hd), ("embed", "kv_heads", "head_dim"),
-                           "scaled", mask_dims={1: cfg.num_kv_heads}),
+                           "scaled", mask_dims={1: cfg.num_kv_heads},
+                           fan_in=d),
             "wv": ParamDef((d, KV, hd), ("embed", "kv_heads", "head_dim"),
-                           "scaled", mask_dims={1: cfg.num_kv_heads}),
+                           "scaled", mask_dims={1: cfg.num_kv_heads},
+                           fan_in=d),
             "wo": ParamDef((Hp, hd, d), ("heads", "head_dim", "embed"),
-                           "scaled", mask_dims={0: H}),
+                           "scaled", mask_dims={0: H}, fan_in=H * hd),
         },
         "mlp": {
             "w_gate": ParamDef((d, ff), ("embed", "mlp"), "scaled"),

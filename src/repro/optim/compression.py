@@ -47,8 +47,7 @@ def pod_allreduce_compressed(grads: Any, err: Any) -> tuple[Any, Any]:
     Bandwidth on the pod links: 1 byte/element (+1 scalar) vs 4.
     """
     q, scales, new_err = compress_int8_ef(grads, err)
-    npods = (jax.lax.axis_size(POD_AXIS) if hasattr(jax.lax, "axis_size")
-             else jax.lax.psum(1, POD_AXIS))  # jax<0.6 lacks lax.axis_size
+    npods = jax.lax.axis_size(POD_AXIS)
 
     def reduce_one(qq, s):
         tot = jax.lax.psum(qq.astype(jnp.int32), POD_AXIS)

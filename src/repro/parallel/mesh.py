@@ -8,12 +8,11 @@ Functions only — importing this module never touches jax device state.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 
 import jax
-
-from repro.parallel.compat import make_mesh as _compat_make_mesh, use_mesh  # noqa: F401
 
 POD_AXIS = "pod"
 DATA_AXIS = "data"
@@ -50,17 +49,30 @@ SINGLE_POD = MeshSpec(shape=(16, 16), axes=(DATA_AXIS, MODEL_AXIS))
 MULTI_POD = MeshSpec(shape=(2, 16, 16), axes=(POD_AXIS, DATA_AXIS, MODEL_AXIS))
 
 
+def make_auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with GSPMD-auto axis types: the sharding rules
+    here annotate with ``PartitionSpec`` and let the partitioner fill in
+    the rest, which explicit axes (jax's default) would refuse."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
+def use_mesh(mesh):
+    """Context manager putting ``mesh`` in scope (None -> no-op)."""
+    return contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh)
+
+
 def make_mesh(spec: MeshSpec) -> jax.sharding.Mesh:
     """Build a jax Mesh for ``spec`` from the currently visible devices."""
-    return _compat_make_mesh(spec.shape, spec.axes)
+    return make_auto_mesh(spec.shape, spec.axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0) -> jax.sharding.Mesh:
     """Small mesh for CPU tests (uses however many devices are visible)."""
     if pod:
-        return _compat_make_mesh((pod, data, model),
-                                 (POD_AXIS, DATA_AXIS, MODEL_AXIS))
-    return _compat_make_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
+        return make_auto_mesh((pod, data, model),
+                              (POD_AXIS, DATA_AXIS, MODEL_AXIS))
+    return make_auto_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
 
 
 def batch_axes(mesh_or_spec) -> tuple[str, ...]:

@@ -41,9 +41,13 @@ phases' published signals.
 
 Workers are threads, not OS processes: one JAX runtime serves all
 engines (this is the single-host analogue; the TCP control plane is
-exactly what a multi-host deployment would speak).  Model parameters
-are built once and shared across workers — co-tenants of one
-accelerator, as in SYNERGY's multiplexing argument.
+exactly what a multi-host deployment would speak), which is also what a
+TPU host needs — a chip belongs to one process.  Worker ``i`` is placed
+on local device ``i mod n_devices``: its params copy, KV pool and
+compiled builds live there, so a four-chip host runs four replicas and
+a one-device host runs co-tenants of one accelerator, as in SYNERGY's
+multiplexing argument.  Model parameters are built once and copied to
+each worker's device.
 """
 from __future__ import annotations
 
@@ -53,6 +57,7 @@ import threading
 import time
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.configs.model_config import ModelConfig
@@ -65,6 +70,7 @@ from repro.core.scheduler import (
 )
 from repro.core.targets import Platform, TPU_PLATFORM
 from repro.core.thresholds import ThresholdTable
+from repro.models.model import build_model
 from repro.serve.api import GenerationRequest, RequestHandle, RequestOutput
 from repro.serve.batch import KVSpan
 from repro.serve.engine import ContinuousBatchingEngine
@@ -129,25 +135,27 @@ class EngineWorker:
         caller-facing results are the RequestHandles, which resolve the
         step each request finishes (reading a worker-side dict here
         would race the front-end, whose drain() returns as soon as the
-        handles resolve)."""
-        while not self._stop.is_set():
-            busy = False
-            while self._prefill_q:
-                req = self._prefill_q.popleft()
-                # publish load BEFORE the span: prefill_to_span never
-                # enters run(), so this is the prefill phase's pressure
-                # feed to the central policy
-                self.engine._publish_signals()
-                payload = self.engine.prefill_to_span(req).to_bytes()
-                if self.on_handoff is not None:
-                    self.on_handoff(req, payload)
-                busy = True
-            if len(self.engine.queue) or self.engine.slots.active:
-                self.engine.run()
-                busy = True
-            if not busy:
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
+        handles resolve).  Host inputs the loop builds land directly on
+        the worker's device."""
+        with jax.default_device(self.engine.device):
+            while not self._stop.is_set():
+                busy = False
+                while self._prefill_q:
+                    req = self._prefill_q.popleft()
+                    # publish load BEFORE the span: prefill_to_span never
+                    # enters run(), so this is the prefill phase's pressure
+                    # feed to the central policy
+                    self.engine._publish_signals()
+                    payload = self.engine.prefill_to_span(req).to_bytes()
+                    if self.on_handoff is not None:
+                        self.on_handoff(req, payload)
+                    busy = True
+                if len(self.engine.queue) or self.engine.slots.active:
+                    self.engine.run()
+                    busy = True
+                if not busy:
+                    self._wake.wait(timeout=0.05)
+                    self._wake.clear()
 
     def submit(self, request: GenerationRequest,
                on_token=None) -> RequestHandle:
@@ -186,7 +194,8 @@ class ClusterFrontEnd:
     ``TcpSchedulerServer`` on loopback; ``"inproc"`` wires the workers
     straight to the server object.  ``engine_kwargs`` (max_slots,
     max_seq, paged, block_size, ...) apply to every worker.  Parameters
-    are built once (worker 0) and shared.
+    are built once and copied to each worker's device (worker ``i`` on
+    local device ``i mod n_devices``).
 
     ``roles`` (one per worker, e.g. ``("prefill", "decode")``) enables
     disaggregated serving: requests route decode-first (the least-loaded
@@ -238,12 +247,18 @@ class ClusterFrontEnd:
             if transport == "tcp":
                 self._tcp = TcpSchedulerServer(self.server)
                 address = self._tcp.start()
+            if params is None:
+                params = build_model(cfg, engine_kwargs.get("mesh")).init(
+                    jax.random.PRNGKey(seed))
+            devices = jax.local_devices()
             for i in range(n_engines):
+                w_params = params
+                if engine_kwargs.get("mesh") is None:
+                    w_params = jax.device_put(
+                        params, devices[i % len(devices)])
                 w = EngineWorker(f"{worker_prefix}{i}", cfg, self.server,
                                  scheduler_address=address, role=roles[i],
-                                 params=params, seed=seed, **engine_kwargs)
-                if params is None:
-                    params = w.engine.params      # share across workers
+                                 params=w_params, seed=seed, **engine_kwargs)
                 self.workers.append(w)
             # disaggregation plumbing: decode-capable workers register a
             # span sink under their worker_id; prefill workers hand

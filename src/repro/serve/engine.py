@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.model_config import ModelConfig
+from repro.core.binary import committed_device
 from repro.core.function import MigratableFunction
 from repro.core.policy import (
     LoadSignals, PinAccel, PinHost, SchedulingPolicy, ewma, resolve_policy,
@@ -61,6 +62,10 @@ from repro.serve import spec as spec_lib
 
 _BACKEND_DEPRECATION_WARNED = False
 _ON_STEP_DEPRECATION_WARNED = False
+# dense draft-cache rows are whole multiples of this many positions:
+# kernels.ops.gqa_decode_ragged views a row as blocks of its default
+# block_k, and a block's position count must tile (8k rows) on TPU
+DRAFT_BLOCK = 128
 
 
 @dataclasses.dataclass
@@ -477,6 +482,10 @@ class ContinuousBatchingEngine:
         if params is None:
             params = self.model.init(jax.random.PRNGKey(seed))
         self.params = params
+        # params committed to one device (a cluster replica on its own
+        # chip) pin the engine there: its caches are allocated and
+        # committed on that device and its AOT builds compile for it
+        self.device = committed_device(params)
         self.queue = RequestQueue()
         if paged:
             # default pool = the dense engine's memory footprint
@@ -487,8 +496,8 @@ class ContinuousBatchingEngine:
             self.slots: SlotManager = PagedSlotManager(
                 max_slots, block_size, nb, max_seq=max_seq,
                 prefix_cache=prefix_cache)
-            self.cache = self.model.init_paged_cache(nb + 1, block_size,
-                                                     lane_align=lane_align)
+            self.cache = self._on_device(lambda: self.model.init_paged_cache(
+                nb + 1, block_size, lane_align=lane_align))
             # scatter a prefill's KV blocks into the pool at the slot's
             # physical block ids (one fused donated update, like the
             # dense row write below); jit specializes per block count
@@ -566,7 +575,8 @@ class ContinuousBatchingEngine:
             self._scatter_span = jax.jit(scatter_span, donate_argnums=(0,))
         else:
             self.slots = SlotManager(max_slots, max_seq)
-            self.cache = self.model.init_cache(max_slots, max_seq)
+            self.cache = self._on_device(
+                lambda: self.model.init_cache(max_slots, max_seq))
         # direct-path (no-runtime) step functions honour the pinned
         # policy; no policy (or PinHost) serves on HOST math.  Both
         # steps sample IN-GRAPH and return tokens, not logits.
@@ -618,12 +628,17 @@ class ContinuousBatchingEngine:
             dparams = (spec_draft_params if spec_draft_params is not None
                        else spec_lib.share_draft_params(self.params,
                                                         dcfg.num_layers))
-            dcache = draft_model.init_cache(max_slots,
-                                            max_seq + spec_draft_len)
+            # the row is widened by draft_len so the chain's last write
+            # never clamps at the edge, then rounded up to whole
+            # DRAFT_BLOCK-position blocks: the ACCEL draft decode views
+            # the dense row as blocks of that size (a legal TPU tile)
+            self._spec_width = -(-(max_seq + spec_draft_len)
+                                 // DRAFT_BLOCK) * DRAFT_BLOCK
+            dcache = self._on_device(lambda: draft_model.init_cache(
+                max_slots, self._spec_width))
             self.spec = spec_lib.SpecDecoder(
                 model=draft_model, cfg=dcfg, params=dparams, cache=dcache,
                 draft_len=spec_draft_len)
-            self._spec_width = max_seq + spec_draft_len
             # content-addressed host->device cache for the small
             # per-round batch vectors that rarely change between rounds
             # (sampling leaves, block table, n_valid) — see _spec_put
@@ -685,6 +700,14 @@ class ContinuousBatchingEngine:
         self.reset_stats()
         if runtime is not None:
             self._prepare_runtime(runtime, fn_prefix, eager_accel)
+
+    def _on_device(self, make):
+        """Build an array tree (``make()``) on the engine's device and
+        commit it there, so every step that touches it runs there too."""
+        if self.device is None:
+            return make()
+        with jax.default_device(self.device):
+            return jax.device_put(make(), self.device)
 
     def reset_stats(self) -> None:
         """Zero the per-serve counters (benchmarks call this after their

@@ -48,6 +48,11 @@ prefill/decode role split over real processes.  All model parameters
 are rebuilt deterministically in each worker from the shared seed
 (``model.init(PRNGKey(seed))``), so every process serves identical
 weights without shipping arrays over a pipe.
+
+CPU only: every worker starts its own JAX runtime, and a TPU chip
+belongs to one process, so on a TPU backend the front-end refuses to
+start (``require_cpu_backend``).  A TPU host runs the threaded
+``ClusterFrontEnd``, which places one replica per device in one process.
 """
 from __future__ import annotations
 
@@ -62,6 +67,7 @@ import threading
 import time
 from typing import Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.policy import PolicyLike
@@ -440,6 +446,16 @@ class ClusterSupervisor:
         return (time.monotonic() - beat["t"]) > self.liveness_deadline_s
 
 
+def require_cpu_backend() -> None:
+    """Refuse to spawn process workers where they cannot get a device."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "ProcClusterFrontEnd is CPU-only: each worker process starts "
+            "its own JAX runtime, and a TPU chip belongs to one process "
+            "(this one already holds it).  On a TPU host use the threaded "
+            "ClusterFrontEnd, which places one replica per device.")
+
+
 class ProcClusterFrontEnd:
     """N OS-process engine workers, one central scheduler, one
     ``submit()`` surface — the ``ClusterFrontEnd`` contract over real
@@ -471,6 +487,7 @@ class ProcClusterFrontEnd:
                  liveness_deadline_s: float = 10.0,
                  spawn_timeout_s: float = 300.0,
                  **engine_kwargs):
+        require_cpu_backend()
         if n_workers < 1:
             raise ValueError(f"need at least one worker: {n_workers}")
         if roles is None:

@@ -124,7 +124,7 @@ def init_train_state(model: Model, tcfg: TrainConfig,
     ospecs = opt.state_specs(pspecs, pshapes, dp)
 
     ns = lambda tree: named_tree(mesh, tree)
-    from repro.parallel.compat import use_mesh
+    from repro.parallel.mesh import use_mesh
     with use_mesh(mesh):
         params = jax.jit(model.init, out_shardings=ns(pspecs))(key)
         opt_state = jax.jit(opt.init, out_shardings=ns(ospecs))(params)

@@ -121,7 +121,7 @@ class Trainer:
 
     def _run_once(self, steps, injector, log_every):
         params, opt_state, start = self.init_or_restore()
-        from repro.parallel.compat import use_mesh
+        from repro.parallel.mesh import use_mesh
         ctx = use_mesh(self.mesh)
         with ctx:
             for step in range(start, steps):

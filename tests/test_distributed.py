@@ -34,7 +34,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         from repro.configs.model_config import ShapeConfig
         from repro.models.model import Model, build_model
         from repro.models.transformer import Geometry
-        from repro.parallel.compat import make_mesh, use_mesh
+        from repro.parallel.mesh import make_auto_mesh as make_mesh, use_mesh
         from repro.parallel.sharding import named_tree
 
         cfg = dataclasses.replace(reduced(ARCHS["smollm-135m"]),
@@ -80,7 +80,7 @@ def test_moe_expert_parallel_matches_unsharded():
         from repro.configs import ARCHS, reduced
         from repro.configs.model_config import ShapeConfig
         from repro.models.model import build_model
-        from repro.parallel.compat import make_mesh, use_mesh
+        from repro.parallel.mesh import make_auto_mesh as make_mesh, use_mesh
         from repro.parallel.sharding import named_tree
 
         cfg = dataclasses.replace(reduced(ARCHS["olmoe-1b-7b"]),
@@ -118,7 +118,7 @@ def test_elastic_restore_onto_different_mesh(tmp_path):
         import jax, jax.numpy as jnp
         from repro.configs import ARCHS, reduced
         from repro.configs.model_config import ShapeConfig, TrainConfig
-        from repro.parallel.compat import make_mesh
+        from repro.parallel.mesh import make_auto_mesh as make_mesh
         from repro.train.trainer import Trainer
 
         cfg = reduced(ARCHS["smollm-135m"])
@@ -145,7 +145,7 @@ def test_multipod_mesh_and_grad_compression():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.optim.compression import pod_allreduce_compressed
-        from repro.parallel.compat import make_mesh, shard_map, use_mesh
+        from repro.parallel.mesh import make_auto_mesh as make_mesh, use_mesh
         from jax.sharding import PartitionSpec as P
 
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
@@ -155,9 +155,9 @@ def test_multipod_mesh_and_grad_compression():
         def f(g, e):
             return pod_allreduce_compressed(g, e)
 
-        sm = shard_map(f, mesh=mesh,
-                       in_specs=(P(), P()), out_specs=(P(), P()),
-                       check_vma=False)
+        sm = jax.shard_map(f, mesh=mesh,
+                           in_specs=(P(), P()), out_specs=(P(), P()),
+                           check_vma=False)
         with use_mesh(mesh):
             mean, new_err = jax.jit(sm)(grads, err)
         np.testing.assert_allclose(np.asarray(mean["w"]),
@@ -177,7 +177,7 @@ def test_dryrun_single_cell_mini():
         from repro.configs import get_arch, get_shape
         from repro.launch.dryrun import build_step
         from repro.models.model import build_model
-        from repro.parallel.compat import make_mesh, peak_memory_bytes, use_mesh
+        from repro.parallel.mesh import make_auto_mesh as make_mesh, use_mesh
         from repro.launch.hlo_cost import analyze
 
         mesh = make_mesh((2, 4), ("data", "model"))
@@ -187,7 +187,7 @@ def test_dryrun_single_cell_mini():
         with use_mesh(mesh):
             jitted, specs = build_step(model, cfg, shape, mesh)
             compiled = jitted.lower(*specs).compile()
-        peak = peak_memory_bytes(compiled.memory_analysis())
+        peak = compiled.memory_analysis().peak_memory_in_bytes
         assert peak > 0
         r = analyze(compiled.as_text())
         assert r["flops"] > 1e12

@@ -8,6 +8,7 @@ import pytest
 
 from repro.core.function import FunctionRegistry, MigratableFunction
 from repro.core.migration import AbiMismatch, check_abi, migrate, migration_bytes
+from repro.core.policy import LatencyAwarePolicy, PinAccel
 from repro.core.runtime import XarTrekRuntime
 from repro.core.targets import TargetKind
 from repro.kernels import ops, ref
@@ -79,11 +80,16 @@ def test_runtime_threshold_adaptation_drains_slow_target(key):
     args = _data(key)
     # seed x86_exec as the estimation step would (Table-1 measurement);
     # without it Algorithm 1 has nothing to compare ACCEL against
+    # (best of five: one timing taken while other test workers load the
+    # cores can overstate HOST several-fold and hide the slow ACCEL)
     host_jit = jax.jit(_host_knn)
     jax.block_until_ready(host_jit(*args))
-    t0 = time.perf_counter()
-    jax.block_until_ready(host_jit(*args))
-    host_ms = (time.perf_counter() - t0) * 1e3
+    samples = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(host_jit(*args))
+        samples.append((time.perf_counter() - t0) * 1e3)
+    host_ms = min(samples)
     rt.prepare("knn2", *args, table_row={"fpga_thr": -0.5, "arm_thr": 1e9,
                                          "x86_exec": host_ms})
     rt.bank.load_sync("knn2")
@@ -93,6 +99,47 @@ def test_runtime_threshold_adaptation_drains_slow_target(key):
         targets.append(rt.call_log[-1]["target"])
     assert targets[0] == "accel"
     assert targets[-1] == "host", f"threshold never adapted: {targets}"
+
+
+def _failing_accel_registry(name):
+    """ACCEL is a Pallas build lowered natively: traceable (so the ABI
+    check passes) but refused by the compiler on a CPU-only backend —
+    the same failure class as a block shape the TPU lowering rejects."""
+    reg = FunctionRegistry()
+    reg.register(MigratableFunction(
+        name, name,
+        {TargetKind.HOST: _host_knn,
+         TargetKind.ACCEL: lambda *a: ops.knn_digits(*a, interpret=False)}))
+    return reg
+
+
+@pytest.mark.parametrize("policy", [PinAccel(), LatencyAwarePolicy(
+    queue_depth_hi=0)], ids=["pin_accel", "latency_aware"])
+def test_failed_accel_load_raises_instead_of_serving_host(key, policy):
+    """A failed ACCEL load is recorded: ``load_sync`` re-raises it, and
+    the next call whose policy wants ACCEL raises too — HOST may stand
+    in only while a load is still in flight, never for a broken build."""
+    if jax.default_backend() != "cpu":
+        pytest.skip("needs a backend that refuses native Pallas lowering")
+    rt = XarTrekRuntime(registry=_failing_accel_registry("knn_bad"),
+                        policy=policy)
+    args = _data(key)
+    rt.prepare("knn_bad", *args)                  # async pre-configuration
+    with pytest.raises(RuntimeError, match="failed to load"):
+        rt.bank.load_sync("knn_bad")
+    assert not rt.bank.is_resident("knn_bad")
+    with pytest.raises(RuntimeError, match="failed to load"):
+        rt.call("knn_bad", *args)
+    assert rt.call_log == []                      # nothing served on HOST
+
+
+def test_failed_eager_accel_load_raises_at_prepare(key):
+    if jax.default_backend() != "cpu":
+        pytest.skip("needs a backend that refuses native Pallas lowering")
+    rt = XarTrekRuntime(registry=_failing_accel_registry("knn_bad2"),
+                        policy=PinAccel())
+    with pytest.raises(RuntimeError, match="failed to load"):
+        rt.prepare("knn_bad2", *_data(key), eager_accel=True)
 
 
 def test_runtime_abi_check_rejects_mismatch(key):
