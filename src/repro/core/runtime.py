@@ -7,15 +7,21 @@ Ties the pieces together for *real* jitted step functions:
     asynchronous load (FPGA pre-configuration), seed the threshold table.
   * ``call`` is the instrumented call site: scheduler-client query ->
     execute the chosen compiled variant -> measure -> report (Alg. 1).
+    It emits the profiler spans ``runtime.call`` (args ``fn``,
+    ``target``), and inside it ``runtime.decide`` (the scheduler-client
+    exchange) and ``runtime.wait`` (the variant's execution to
+    ``block_until_ready``).
   * live state handed between variants is resharded via migration.py
     when targets disagree on shardings.
 """
 from __future__ import annotations
 
+import collections
 import time
 from typing import Any, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.binary import MultiTargetBinary, abstract_args
 from repro.core.function import GLOBAL_REGISTRY, FunctionRegistry
@@ -28,6 +34,48 @@ from repro.core.scheduler import (
 )
 from repro.core.targets import Platform, TargetKind, TPU_PLATFORM
 from repro.core.thresholds import ThresholdTable
+
+# records a CallLog keeps: far above the ~2,300 calls of a 51 s serving
+# window, so a reader of one window sees all of its calls
+CALL_LOG_MAX = 65536
+
+
+class CallLog(collections.deque):
+    """The runtime's most recent call records, oldest first, at most
+    ``maxlen`` of them, with running counts of every call appended since
+    the last ``clear()``: calls per function and target, and migrations
+    per function (consecutive calls of it on different targets).  The
+    counts stay exact past the bound.  Indexes and slices like a list."""
+
+    def __init__(self, maxlen: int = CALL_LOG_MAX):
+        super().__init__(maxlen=maxlen)
+        self._zero()
+
+    def _zero(self) -> None:
+        self.calls = 0
+        self.per_fn: dict[str, dict[str, int]] = {}
+        self.migrations: dict[str, int] = {}
+        self._last: dict[str, str] = {}
+
+    def append(self, rec: dict) -> None:
+        super().append(rec)
+        fn, target = rec["fn"], rec["target"]
+        self.calls += 1
+        d = self.per_fn.setdefault(fn, {})
+        d[target] = d.get(target, 0) + 1
+        prev = self._last.get(fn)
+        if prev is not None and prev != target:
+            self.migrations[fn] = self.migrations.get(fn, 0) + 1
+        self._last[fn] = target
+
+    def clear(self) -> None:
+        super().clear()
+        self._zero()
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return list(self)[i]
+        return super().__getitem__(i)
 
 
 class XarTrekRuntime:
@@ -75,7 +123,7 @@ class XarTrekRuntime:
                                           policy=policy or "xartrek")
         self._scheduler_address = scheduler_address
         self._clients: dict[str, object] = {}
-        self.call_log: list[dict] = []
+        self.call_log = CallLog()
 
     # ----------------------------------------------------------- prepare
     def prepare(self, fn_name: str, *example_args,
@@ -142,42 +190,46 @@ class XarTrekRuntime:
         Args may differ in shape from the ``prepare`` examples (ragged
         continuous-batching prefills): the binary's shape-bucket cache
         then compiles/reuses a variant for the exact signature."""
-        fn = self.registry.get(fn_name)
-        binary = self.binaries[fn_name]
-        client = self._client(fn.app)
+        with TraceAnnotation("runtime.call", fn=fn_name) as span:
+            fn = self.registry.get(fn_name)
+            binary = self.binaries[fn_name]
+            client = self._client(fn.app)
 
-        decision = client.before_call()
-        kind = decision.target
-        if kind == TargetKind.ACCEL and not binary.is_compiled(kind):
-            # HOST stands in only while the ACCEL load is in flight (the
-            # paper's latency hiding); a load that failed raises here
-            self.bank.raise_if_failed(fn_name)
-            kind = TargetKind.HOST
-        if kind not in fn.variants:
-            kind = TargetKind.HOST
+            with TraceAnnotation("runtime.decide"):
+                decision = client.before_call()
+            kind = decision.target
+            if kind == TargetKind.ACCEL and not binary.is_compiled(kind):
+                # HOST stands in only while the ACCEL load is in flight
+                # (the paper's latency hiding); a load that failed raises
+                self.bank.raise_if_failed(fn_name)
+                kind = TargetKind.HOST
+            if kind not in fn.variants:
+                kind = TargetKind.HOST
+            span.set_metadata(target=kind.value)
 
-        if state_shardings and kind in state_shardings:
-            args = migrate(args, state_shardings[kind])
+            if state_shardings and kind in state_shardings:
+                args = migrate(args, state_shardings[kind])
 
-        # resolve (and possibly bucket-compile) BEFORE the timed region:
-        # compile time must not reach Algorithm 1 as execution time or
-        # hold the load monitor elevated
-        variant = binary.variant_for(kind, args)
+            # resolve (and possibly bucket-compile) BEFORE the timed
+            # region: compile time must not reach Algorithm 1 as
+            # execution time or hold the load monitor elevated
+            variant = binary.variant_for(kind, args)
 
-        self.monitor.job_started(kind)
-        t0 = time.perf_counter()
-        try:
-            out = variant(*args)
-            out = jax.block_until_ready(out)
-        finally:
-            self.monitor.job_finished(kind)
-        dt = time.perf_counter() - t0
-        binary.note_exec(kind, dt * 1e3)
-        client.after_call(kind, dt * 1e3)
-        self.call_log.append({"fn": fn_name, "target": kind.value,
-                              "ms": dt * 1e3,
-                              "reconfigure": decision.reconfigure})
-        return out
+            self.monitor.job_started(kind)
+            with TraceAnnotation("runtime.wait"):
+                t0 = time.perf_counter()
+                try:
+                    out = variant(*args)
+                    out = jax.block_until_ready(out)
+                finally:
+                    self.monitor.job_finished(kind)
+                dt = time.perf_counter() - t0
+            binary.note_exec(kind, dt * 1e3)
+            client.after_call(kind, dt * 1e3)
+            self.call_log.append({"fn": fn_name, "target": kind.value,
+                                  "t": t0, "ms": dt * 1e3,
+                                  "reconfigure": decision.reconfigure})
+            return out
 
     # ------------------------------------------------------------- stats
     def summary(self) -> dict:
@@ -190,29 +242,22 @@ class XarTrekRuntime:
         can prove which backend served tokens, not just which was
         registered.
         """
+        log = self.call_log
         per_target = {k.value: 0 for k in TargetKind}
-        per_fn_calls: dict[str, dict[str, int]] = {}
-        migrations: dict[str, int] = {}
-        last: dict[str, str] = {}
-        for rec in self.call_log:
-            per_target[rec["target"]] += 1
-            d = per_fn_calls.setdefault(rec["fn"], {})
-            d[rec["target"]] = d.get(rec["target"], 0) + 1
-            prev = last.get(rec["fn"])
-            if prev is not None and prev != rec["target"]:
-                migrations[rec["fn"]] = migrations.get(rec["fn"], 0) + 1
-            last[rec["fn"]] = rec["target"]
+        for calls in log.per_fn.values():
+            for target, n in calls.items():
+                per_target[target] += n
         per_function = {}
         for name, binary in self.binaries.items():
             per_function[name] = {
-                "calls": per_fn_calls.get(name, {}),
+                "calls": dict(log.per_fn.get(name, {})),
                 "compiles": {k.value: dict(v)
                              for k, v in binary.compile_stats.items()},
-                "migrations": migrations.get(name, 0),
+                "migrations": log.migrations.get(name, 0),
             }
-        return {"calls": len(self.call_log), "per_target": per_target,
+        return {"calls": log.calls, "per_target": per_target,
                 "per_function": per_function,
-                "migrations": sum(migrations.values()),
+                "migrations": sum(log.migrations.values()),
                 "bank": dict(self.bank.stats),
                 "shape_buckets": {name: dict(b.shape_stats)
                                   for name, b in self.binaries.items()

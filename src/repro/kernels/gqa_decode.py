@@ -119,6 +119,7 @@ def gqa_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, 1, hd), q.dtype),
         interpret=interpret,
+        name="gqa_decode",
     )(idx, q, k_cache, v_cache)
 
 
@@ -235,11 +236,12 @@ def _paged_int8_kernel(tbl_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
 
 
 def _paged_call(kernel, extra_specs, q, tables, lengths, *operands,
-                interpret: bool):
+                name: str, interpret: bool):
     """Shared ``pallas_call`` of the paged decode kernels: grid
     ``(B, KV, NBT)``; ``operands`` are the heads-major page planes (and
     scale tiles, with ``extra_specs`` their block specs) followed by the
-    current token's k/v."""
+    current token's k/v.  ``name`` is the kernel's name in the compiled
+    program and in a device trace."""
     B, KV, G, hd = q.shape
     block_size = operands[0].shape[2]
     page_spec = pl.BlockSpec((1, 1, block_size, hd),
@@ -265,6 +267,7 @@ def _paged_call(kernel, extra_specs, q, tables, lengths, *operands,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
+        name=name,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, *operands)
 
 
@@ -286,7 +289,10 @@ def paged_gqa_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                                scale=1.0 / np.sqrt(q.shape[-1]))
     return _paged_call(kernel, lambda page: (page, page), q, tables, lengths,
                        heads_major(k_pages), heads_major(v_pages),
-                       k_new, v_new, interpret=interpret)
+                       k_new, v_new,
+                       # the op name a device trace shows: readers of the
+                       # trace find the paged decode attention by it
+                       name="paged_gqa_decode", interpret=interpret)
 
 
 def paged_gqa_decode_int8(q: jax.Array, k_pages: jax.Array,
@@ -316,4 +322,5 @@ def paged_gqa_decode_int8(q: jax.Array, k_pages: jax.Array,
                        q, tables, lengths,
                        heads_major(k_pages), scale_tiles(k_scale),
                        heads_major(v_pages), scale_tiles(v_scale),
-                       k_new, v_new, interpret=interpret)
+                       k_new, v_new, name="paged_gqa_decode_int8",
+                       interpret=interpret)

@@ -142,11 +142,12 @@ def _prefill_int8_kernel(tbl_ref, off_ref, len_ref, q_ref, k_ref, ks_ref,
 
 
 def _prefill_call(kernel, extra_specs, q, tables, offsets, lengths,
-                  *operands, interpret: bool):
+                  *operands, name: str, interpret: bool):
     """Shared ``pallas_call`` of the paged prefill kernels: grid
     ``(B, KV, NBT)``; ``operands`` are the heads-major page planes (and
     scale tiles, with ``extra_specs`` their block specs) followed by the
-    chunk's heads-major k/v (B, KV, W, hd)."""
+    chunk's heads-major k/v (B, KV, W, hd).  ``name`` is the kernel's
+    name in the compiled program and in a device trace."""
     B, KV, WG, hd = q.shape
     block_size = operands[0].shape[2]
     W = operands[-1].shape[2]
@@ -175,6 +176,7 @@ def _prefill_call(kernel, extra_specs, q, tables, offsets, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, WG, hd), q.dtype),
         interpret=interpret,
+        name=name,
     )(tables.astype(jnp.int32), offsets.astype(jnp.int32),
       lengths.astype(jnp.int32), q, *operands)
 
@@ -202,7 +204,7 @@ def paged_gqa_prefill(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                          offsets, lengths,
                          heads_major(k_pages), heads_major(v_pages),
                          heads_major(k_new), heads_major(v_new),
-                         interpret=interpret)
+                         name="paged_gqa_prefill", interpret=interpret)
 
 
 def paged_gqa_prefill_int8(q: jax.Array, k_pages: jax.Array,
@@ -230,4 +232,4 @@ def paged_gqa_prefill_int8(q: jax.Array, k_pages: jax.Array,
                          heads_major(k_pages), scale_tiles(k_scale),
                          heads_major(v_pages), scale_tiles(v_scale),
                          heads_major(k_new), heads_major(v_new),
-                         interpret=interpret)
+                         name="paged_gqa_prefill_int8", interpret=interpret)

@@ -73,4 +73,5 @@ def window_scores(img: jax.Array, feats: jax.Array, *, win: int = 24,
                                lambda gy, gx: (gy, gx, 0)),
         out_shape=jax.ShapeDtypeStruct((ny, nx, F), jnp.float32),
         interpret=interpret,
+        name="window_scores",
     )(img, feats)

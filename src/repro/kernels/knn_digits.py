@@ -42,4 +42,5 @@ def hamming_distances(test: jax.Array, train: jax.Array, *,
         out_specs=pl.BlockSpec((block_t, block_n), lambda ti, ni: (ti, ni)),
         out_shape=jax.ShapeDtypeStruct((Nt, Nn), jnp.int32),
         interpret=interpret,
+        name="hamming_distances",
     )(test, train)

@@ -70,4 +70,5 @@ def grouped_matmul(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
         interpret=interpret,
+        name="grouped_matmul",
     )(x, w, group_sizes.astype(jnp.int32))

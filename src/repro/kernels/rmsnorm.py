@@ -37,4 +37,5 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-5,
         out_specs=pl.BlockSpec((block_rows, d), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((R, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x, w)

@@ -102,5 +102,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt[..., None], A[:, None], Bm, Cm)
     return y, sfin

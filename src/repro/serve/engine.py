@@ -26,6 +26,17 @@ step through an XarTrekRuntime so the scheduler (Algorithm 2) migrates
 steps between HOST/AUX/ACCEL as load changes — the Figure-6 throughput
 experiment's analogue, with the continuous engine playing the
 multi-tenant arrival stream.
+
+Profiler spans (``jax.profiler.TraceAnnotation``: host events on the
+device trace's clock, recorded only while a profiler runs):
+``engine.iteration`` (one pass of the ``run()`` loop; args ``queued``,
+``active``), ``engine.admit`` (``req``, ``feed`` tokens),
+``engine.prefill_chunk`` (``offset``, ``width``, ``bucket``),
+``engine.decode`` (``step``, ``rows``, ``ctx`` = the pool positions the
+step reads) with ``engine.blocks``, ``engine.upload``,
+``engine.readback`` and ``engine.emit`` inside it, and ``engine.sleep``
+(waiting for an arrival).  Each engine's loop runs on its own thread,
+which the profiler gives its own line.
 """
 from __future__ import annotations
 
@@ -39,6 +50,7 @@ from typing import Iterable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.model_config import ModelConfig
 from repro.core.binary import committed_device
@@ -1103,7 +1115,8 @@ class ContinuousBatchingEngine:
         # request's sampling spec (same math, same weights, same tokens)
         span = self._spans.pop(req.req_id, None)
         if span is not None:
-            self._admit_span(req, span, now)
+            with TraceAnnotation("engine.admit", req=req.req_id, feed=0):
+                self._admit_span(req, span, now)
             return
         resume = self._resume.pop(req.req_id, None)
         if resume is None:
@@ -1111,6 +1124,13 @@ class ContinuousBatchingEngine:
         else:
             feed = np.concatenate(
                 [req.prompt, np.asarray(resume[0][:-1], np.int32)])
+        with TraceAnnotation("engine.admit", req=req.req_id, feed=len(feed)):
+            self._admit_feed(req, feed, resume, now)
+
+    def _admit_feed(self, req: GenerationRequest, feed: np.ndarray, resume,
+                    now: float) -> None:
+        """Admit ``req`` by prefilling ``feed`` (its prompt, plus the
+        replayed tokens of a preempted request being resumed)."""
         S = len(feed)
         if self.paged and self._step_budget is not None:
             # chunked prefill: admit the slot with its blocks but NO
@@ -1278,36 +1298,39 @@ class ContinuousBatchingEngine:
         where it equals the monolithic prefill's first draw."""
         bs = self.slots.block_size
         Cb = prompt_bucket(n_chunk, self.min_bucket)
-        toks = np.zeros((1, Cb), np.int32)
-        toks[0, :n_chunk] = feed[offset:offset + n_chunk]
-        table = np.zeros((1, self.slots.table_width), np.int32)
-        table[0, :len(blocks)] = blocks
-        batch = {"tokens": jnp.asarray(toks),
-                 "offset": jnp.full((1,), offset, jnp.int32),
-                 "length": jnp.full((1,), offset + n_chunk, jnp.int32),
-                 "block_table": jnp.asarray(table),
-                 **sampling_leaves(sampling, 1)}
-        if (self.runtime is not None
-                and self._prefill_ctx_name in self.runtime.registry):
-            tok0, lp0, pc = self.runtime.call(self._prefill_ctx_name,
-                                              self.params, self.cache, batch)
-        else:
-            # no migratable build registered (e.g. prefill_to_span on an
-            # engine prepared without prefix cache or chunking): the
-            # direct jit serves the chunk on the engine's own backend
-            tok0, lp0, pc = self._prefill_ctx(self.params, self.cache, batch)
-        nphys = (Cb + 2 * bs - 2) // bs
-        span = blocks[offset // bs:][:nphys]
-        phys = np.zeros((nphys,), np.int32)
-        phys[:len(span)] = span
-        self.cache = self._scatter_chunk(self.cache, pc,
-                                         jnp.asarray(phys),
-                                         jnp.int32(offset % bs),
-                                         jnp.int32(n_chunk))
-        self.stats["prefill_tokens"] += n_chunk
-        self.stats["prefill_chunks"] += 1
-        hist = self.stats["chunk_hist"]
-        hist[Cb] = hist.get(Cb, 0) + 1
+        with TraceAnnotation("engine.prefill_chunk", offset=offset,
+                             width=n_chunk, bucket=Cb):
+            toks = np.zeros((1, Cb), np.int32)
+            toks[0, :n_chunk] = feed[offset:offset + n_chunk]
+            table = np.zeros((1, self.slots.table_width), np.int32)
+            table[0, :len(blocks)] = blocks
+            batch = {"tokens": jnp.asarray(toks),
+                     "offset": jnp.full((1,), offset, jnp.int32),
+                     "length": jnp.full((1,), offset + n_chunk, jnp.int32),
+                     "block_table": jnp.asarray(table),
+                     **sampling_leaves(sampling, 1)}
+            if (self.runtime is not None
+                    and self._prefill_ctx_name in self.runtime.registry):
+                tok0, lp0, pc = self.runtime.call(
+                    self._prefill_ctx_name, self.params, self.cache, batch)
+            else:
+                # no migratable build registered (e.g. prefill_to_span on
+                # an engine prepared without prefix cache or chunking):
+                # the direct jit serves the chunk on the engine's backend
+                tok0, lp0, pc = self._prefill_ctx(self.params, self.cache,
+                                                  batch)
+            nphys = (Cb + 2 * bs - 2) // bs
+            span = blocks[offset // bs:][:nphys]
+            phys = np.zeros((nphys,), np.int32)
+            phys[:len(span)] = span
+            self.cache = self._scatter_chunk(self.cache, pc,
+                                             jnp.asarray(phys),
+                                             jnp.int32(offset % bs),
+                                             jnp.int32(n_chunk))
+            self.stats["prefill_tokens"] += n_chunk
+            self.stats["prefill_chunks"] += 1
+            hist = self.stats["chunk_hist"]
+            hist[Cb] = hist.get(Cb, 0) + 1
         return tok0, lp0
 
     # ------------------------------------------------- chunked prefill
@@ -1588,48 +1611,61 @@ class ContinuousBatchingEngine:
                     slot.block_hashes = slot.block_hashes[:blk_idx]
 
     def _decode_step(self) -> None:
-        if self.paged:
-            self._ensure_decode_blocks()
-        active = self.slots.active_slots()
-        if not active:                     # everything was preempted
-            return
-        batch = {"tokens": jnp.asarray(self.slots.token_vector()),
-                 "index": jnp.asarray(self.slots.index_vector()),
-                 **self.slots.sampling_vectors()}
-        if self.paged:
-            batch["block_table"] = jnp.asarray(self.slots.block_table())
-        if self.runtime is not None:
-            toks, logps, self.cache = self.runtime.call(
-                self._decode_name, self.params, self.cache, batch)
-            toks = np.asarray(toks)        # (B,) sampled in-graph
-        else:
-            t0 = time.perf_counter()
-            toks, logps, self.cache = self._decode(self.params, self.cache,
-                                                   batch)
-            toks = np.asarray(toks)        # forces completion
-            ms = (time.perf_counter() - t0) * 1e3
-            tgt = "accel" if self._direct_impl == "pallas" else "host"
-            self._direct_step_ms[tgt] = ewma(self._direct_step_ms[tgt], ms)
-        self.stats["decode_steps"] += 1
-        self.stats["decode_row_util"] += len(active) / self.slots.max_slots
-        logps = np.asarray(logps)
-        now = self._now()
-        for slot in active:
-            t = int(toks[slot.index])
-            slot.tokens.append(t)
-            slot.logprobs.append(float(logps[slot.index]))
-            slot.last_token = t
-            slot.pos += 1
-            slot.t_last_token = now
-            if (self.prefix_cache
-                    and slot.pos % self.slots.block_size == 0):
-                # a block just filled: key it in the prefix index so a
-                # follow-up request sharing prompt+generated matches it
-                self.slots.register_full_blocks(slot,
-                                                self._kv_tokens(slot))
-            self._sync_handle(slot, now)
-            if slot.done:
-                self._finish(slot, now)
+        with TraceAnnotation("engine.decode",
+                             step=self.stats["decode_steps"]) as span:
+            if self.paged:
+                with TraceAnnotation("engine.blocks"):
+                    self._ensure_decode_blocks()
+            active = self.slots.active_slots()
+            if not active:                     # everything was preempted
+                return
+            with TraceAnnotation("engine.upload"):
+                index = self.slots.index_vector()
+                batch = {"tokens": jnp.asarray(self.slots.token_vector()),
+                         "index": jnp.asarray(index),
+                         **self.slots.sampling_vectors()}
+                if self.paged:
+                    batch["block_table"] = jnp.asarray(
+                        self.slots.block_table())
+            # rows decoded and the pool positions they read
+            span.set_metadata(rows=len(active), ctx=int(index.sum()))
+            if self.runtime is not None:
+                toks, logps, self.cache = self.runtime.call(
+                    self._decode_name, self.params, self.cache, batch)
+            else:
+                t0 = time.perf_counter()
+                toks, logps, self.cache = self._decode(self.params,
+                                                       self.cache, batch)
+            with TraceAnnotation("engine.readback"):
+                toks = np.asarray(toks)        # (B,) sampled in-graph
+                if self.runtime is None:       # the read forced completion
+                    ms = (time.perf_counter() - t0) * 1e3
+                    tgt = "accel" if self._direct_impl == "pallas" else "host"
+                    self._direct_step_ms[tgt] = ewma(
+                        self._direct_step_ms[tgt], ms)
+                logps = np.asarray(logps)
+            self.stats["decode_steps"] += 1
+            self.stats["decode_row_util"] += (len(active)
+                                              / self.slots.max_slots)
+            with TraceAnnotation("engine.emit"):
+                now = self._now()
+                for slot in active:
+                    t = int(toks[slot.index])
+                    slot.tokens.append(t)
+                    slot.logprobs.append(float(logps[slot.index]))
+                    slot.last_token = t
+                    slot.pos += 1
+                    slot.t_last_token = now
+                    if (self.prefix_cache
+                            and slot.pos % self.slots.block_size == 0):
+                        # a block just filled: key it in the prefix index
+                        # so a follow-up request sharing prompt+generated
+                        # matches it
+                        self.slots.register_full_blocks(
+                            slot, self._kv_tokens(slot))
+                    self._sync_handle(slot, now)
+                    if slot.done:
+                        self._finish(slot, now)
 
     # ------------------------------------------------ speculative decode
     def _draft_len(self) -> int:
@@ -1863,45 +1899,50 @@ class ContinuousBatchingEngine:
                 self._handle_for(r)
             self._clock0 = time.perf_counter()
             while len(self.queue) or self.slots.active:
-                now = self._now()
-                self._service_aborts(now)
-                # publish BEFORE admission: the policy deciding this
-                # iteration's steps sees the arrived-but-unadmitted
-                # pressure, and a central scheduler sees it cross-engine
-                self._publish_signals()
-                self._step_budget = (self._prefill_budget()
-                                     if self._chunking else None)
-                while self.slots.has_free():
-                    req = self.queue.pop_arrived(now)
-                    if req is None:
-                        break
-                    if not self._can_admit(req):
-                        # block-exhaustion backpressure: head-of-queue
-                        # waits (front of its arrival cohort) for blocks
-                        self.queue.requeue(req)
-                        break
-                    self._admit(req, now)
-                if self._chunking:
-                    self._advance_prefills(self._step_budget)
-                if self.slots.active:
-                    # speculative round when enabled and the policy's
-                    # draft_len allows it (k=0 = plain decode); a round
-                    # that cannot reserve its fan-out also falls back —
-                    # _decode_step's preempt loop guarantees progress
-                    stepped = False
-                    if self.spec is not None:
-                        k = self._draft_len()
-                        if k >= 1:
-                            stepped = self._spec_step(k)
-                    if not stepped:
-                        self._decode_step()
-                    if self.on_step is not None:
-                        self.on_step(self)
-                else:
-                    nxt = self.queue.next_arrival()
-                    if nxt is None:
-                        break
-                    time.sleep(min(max(nxt - now, 0.0), 0.05) + poll_s)
+                with TraceAnnotation("engine.iteration",
+                                     queued=len(self.queue),
+                                     active=len(self.slots.active)):
+                    now = self._now()
+                    self._service_aborts(now)
+                    # publish BEFORE admission: the policy deciding this
+                    # iteration's steps sees the arrived-but-unadmitted
+                    # pressure, and a central scheduler sees it cross-engine
+                    self._publish_signals()
+                    self._step_budget = (self._prefill_budget()
+                                         if self._chunking else None)
+                    while self.slots.has_free():
+                        req = self.queue.pop_arrived(now)
+                        if req is None:
+                            break
+                        if not self._can_admit(req):
+                            # block-exhaustion backpressure: head-of-queue
+                            # waits (front of its arrival cohort) for blocks
+                            self.queue.requeue(req)
+                            break
+                        self._admit(req, now)
+                    if self._chunking:
+                        self._advance_prefills(self._step_budget)
+                    if self.slots.active:
+                        # speculative round when enabled and the policy's
+                        # draft_len allows it (k=0 = plain decode); a round
+                        # that cannot reserve its fan-out also falls back —
+                        # _decode_step's preempt loop guarantees progress
+                        stepped = False
+                        if self.spec is not None:
+                            k = self._draft_len()
+                            if k >= 1:
+                                stepped = self._spec_step(k)
+                        if not stepped:
+                            self._decode_step()
+                        if self.on_step is not None:
+                            self.on_step(self)
+                    else:
+                        nxt = self.queue.next_arrival()
+                        if nxt is None:
+                            break
+                        with TraceAnnotation("engine.sleep"):
+                            time.sleep(min(max(nxt - now, 0.0), 0.05)
+                                       + poll_s)
         except BaseException:
             for h in list(self._handles.values()):
                 if not h.finished:

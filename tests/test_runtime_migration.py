@@ -9,7 +9,7 @@ import pytest
 from repro.core.function import FunctionRegistry, MigratableFunction
 from repro.core.migration import AbiMismatch, check_abi, migrate, migration_bytes
 from repro.core.policy import LatencyAwarePolicy, PinAccel
-from repro.core.runtime import XarTrekRuntime
+from repro.core.runtime import CallLog, XarTrekRuntime
 from repro.core.targets import TargetKind
 from repro.kernels import ops, ref
 
@@ -130,7 +130,7 @@ def test_failed_accel_load_raises_instead_of_serving_host(key, policy):
     assert not rt.bank.is_resident("knn_bad")
     with pytest.raises(RuntimeError, match="failed to load"):
         rt.call("knn_bad", *args)
-    assert rt.call_log == []                      # nothing served on HOST
+    assert not rt.call_log                        # nothing served on HOST
 
 
 def test_failed_eager_accel_load_raises_at_prepare(key):
@@ -175,3 +175,39 @@ def test_migrate_abi_mismatch_raises(key):
     bad = {"w2": jax.sharding.SingleDeviceSharding(jax.devices()[0])}
     with pytest.raises(AbiMismatch):
         check_abi(state, bad)
+
+
+def test_call_log_counts_past_its_bound():
+    log = CallLog(maxlen=3)
+    for target in ["host", "accel", "accel", "host", "host", "accel", "host"]:
+        log.append({"fn": "f", "target": target, "ms": 1.0})
+    log.append({"fn": "g", "target": "host", "ms": 1.0})
+    assert len(log) == 3 and log.calls == 8
+    assert [(r["fn"], r["target"]) for r in log[-3:]] == [
+        ("f", "accel"), ("f", "host"), ("g", "host")]
+    assert log.per_fn == {"f": {"host": 4, "accel": 3}, "g": {"host": 1}}
+    assert log.migrations == {"f": 4}
+    log.clear()
+    assert (len(log), log.calls, log.per_fn, log.migrations) == (0, 0, {}, {})
+
+
+def test_summary_stays_exact_past_the_call_log_bound():
+    reg = FunctionRegistry()
+    reg.register(MigratableFunction("inc", "counter",
+                                    {TargetKind.HOST: lambda x: x + 1}))
+    rt = XarTrekRuntime(registry=reg)
+    x = jnp.zeros((4,), jnp.int32)
+    rt.prepare("inc", x)
+    rt.call_log = CallLog(maxlen=4)
+    for _ in range(10):
+        x = rt.call("inc", x)
+    assert int(x[0]) == 10
+    assert len(rt.call_log) == 4
+    starts = [r["t"] for r in rt.call_log]
+    assert starts == sorted(starts)
+    s = rt.summary()
+    assert s["calls"] == 10 and s["per_target"]["host"] == 10
+    assert s["per_function"]["inc"]["calls"] == {"host": 10}
+    assert s["migrations"] == 0
+    rt.call_log.clear()
+    assert rt.summary()["calls"] == 0

@@ -14,6 +14,7 @@ and the persistent compilation cache is off around these compiles: an
 entry written for a described chip cannot be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +62,16 @@ def _compile(fn, *specs):
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
+def _kernel_names(hlo: str) -> set:
+    """Names of the Pallas kernels in compiled HLO: each
+    ``tpu_custom_call`` instruction's name without its instance number,
+    which is the op name a device trace shows for it."""
+    return {re.sub(r"(\.\d+)+$", "", line.split(" = ", 1)[0].strip()
+                   .removeprefix("ROOT ").lstrip("%"))
+            for line in hlo.splitlines()
+            if "custom_call_target=\"tpu_custom_call\"" in line}
+
+
 def _pool(sharding, int8: bool):
     """(k, v) page specs plus int8 scale specs (None for bf16)."""
     dt = jnp.int8 if int8 else jnp.bfloat16
@@ -86,6 +97,8 @@ def test_paged_decode_compiles(one_chip, int8):
             q, k, v, kn, vn, t, i, kv_index=KV_INDEX, interpret=False)
         hlo = _compile(fn, q, pages, pages, tok, tok, tables, idx)
     assert "tpu_custom_call" in hlo
+    assert _kernel_names(hlo) == {
+        "paged_gqa_decode_int8" if int8 else "paged_gqa_decode"}
 
 
 @pytest.mark.parametrize("op,width", [
@@ -112,6 +125,38 @@ def test_paged_prefill_at_offset_compiles(one_chip, op, width):
             q, k, v, kn, vn, t, o, n, kv_index=KV_INDEX, interpret=False)
         hlo = _compile(fn, q, pages, pages, chunk, chunk, tables, off, ln)
     assert "tpu_custom_call" in hlo
+    # verify re-exports the prefill-at-offset kernel
+    assert _kernel_names(hlo) == {
+        "paged_gqa_prefill_int8" if int8 else "paged_gqa_prefill"}
+
+
+@pytest.mark.parametrize("kernel", [
+    "paged_gqa_decode", "paged_gqa_decode_int8", "paged_gqa_prefill",
+    "paged_gqa_prefill_int8"])
+def test_paged_kernels_carry_their_names(one_chip, kernel):
+    """Each paged kernel names itself (``pallas_call(name=...)``), called
+    bare, outside the named jit of its ``ops`` wrapper: the op name a
+    device trace shows does not hang on the wrapper's name."""
+    from repro.kernels import gqa_decode, gqa_prefill
+    S = lambda shape, dt=jnp.bfloat16: _spec(one_chip, shape, dt)
+    G, W = HP // KV, 256
+    int8 = kernel.endswith("int8")
+    pages, scales = _pool(one_chip, int8)
+    pool = (pages, scales, pages, scales) if int8 else (pages, pages)
+    tables, idx = S((B, NBT), jnp.int32), S((B,), jnp.int32)
+    if "decode" in kernel:
+        fn = getattr(gqa_decode, kernel)
+        tok = S((B, KV, 1, HDP))
+        args = (S((B, KV, G, HDP)), *pool, tok, tok, tables, idx)
+        kw = {}
+    else:
+        fn = getattr(gqa_prefill, kernel)
+        chunk = S((B, W, KV, HDP))
+        args = (S((B, KV, W * G, HDP)), *pool, chunk, chunk, tables, idx,
+                idx)
+        kw = {"group": G}
+    hlo = _compile(lambda *a: fn(*a, interpret=False, **kw), *args)
+    assert _kernel_names(hlo) == {kernel}
 
 
 def test_dense_decode_compiles(one_chip):
