@@ -52,8 +52,8 @@ def _prefill_accumulate(q_ref, off_ref, len_ref, kn_ref, vn_ref, o_ref,
     """Shared online-softmax body of the paged prefill kernels.
 
     ``k``/``v`` are this grid step's already-dequantised (block_size, hd)
-    f32 planes, exactly as in ``gqa_decode._paged_accumulate`` — the f32
-    and int8 variants differ ONLY in the dequantise step.  The scratch
+    f32 planes — the f32 and int8 variants differ ONLY in the
+    dequantise step.  The scratch
     accumulators carry one (W*group, …) online softmax for the whole
     chunk; the final block step folds the chunk's causal self-attention.
     """

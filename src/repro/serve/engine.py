@@ -33,7 +33,7 @@ device trace's clock, recorded only while a profiler runs):
 ``active``), ``engine.admit`` (``req``, ``feed`` tokens),
 ``engine.prefill_chunk`` (``offset``, ``width``, ``bucket``),
 ``engine.decode`` (``step``, ``rows``, ``ctx`` = the pool positions the
-step reads) with ``engine.blocks``, ``engine.upload``,
+step reads, ``blocks`` = the pool blocks they fill) with ``engine.blocks``, ``engine.upload``,
 ``engine.readback`` and ``engine.emit`` inside it, and ``engine.sleep``
 (waiting for an arrival).  Each engine's loop runs on its own thread,
 which the profiler gives its own line.
@@ -1627,8 +1627,13 @@ class ContinuousBatchingEngine:
                 if self.paged:
                     batch["block_table"] = jnp.asarray(
                         self.slots.block_table())
-            # rows decoded and the pool positions they read
-            span.set_metadata(rows=len(active), ctx=int(index.sum()))
+            # rows decoded, the pool positions they read and (paged) the
+            # pool blocks those positions fill
+            meta = {"rows": len(active), "ctx": int(index.sum())}
+            if self.paged:
+                bs = self.slots.block_size
+                meta["blocks"] = int((-(-index // bs)).sum())
+            span.set_metadata(**meta)
             if self.runtime is not None:
                 toks, logps, self.cache = self.runtime.call(
                     self._decode_name, self.params, self.cache, batch)

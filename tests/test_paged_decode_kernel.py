@@ -4,7 +4,8 @@ The kernel streams a row's physical KV blocks in logical order via
 scalar-prefetched block tables — these tests pin its math to
 ``ref.decode_attention_ref`` (assembling the equivalent dense cache by
 hand) and to the HOST gather-then-attend path across GQA ratios, head
-dims, ragged live-lengths and block sizes.
+dims, ragged live-lengths and block sizes, and show that it never reads
+a table entry past a row's live blocks.
 """
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ from repro.kernels.gqa_decode import paged_gqa_decode as paged_raw
 from repro.models.attention import (
     decode_attention, kv_head_index, paged_decode_attention,
 )
+from repro.models.common import quantize_int8
 
 
 def _rand(key, shape):
@@ -191,6 +193,102 @@ def test_junk_block_and_zero_length_rows_are_well_defined():
     np.testing.assert_allclose(np.asarray(out),
                                np.broadcast_to(np.asarray(vn), out.shape),
                                atol=1e-6)
+
+
+# ------------------------------------------- dead blocks are never read
+
+def _poisoned_problem(seed, BS, NBT, lengths, int8):
+    """Raw-kernel operands whose every whole block past a row's live
+    length, and the junk block 0, is NaN (int8: NaN scales), plus the
+    clean pool the oracle sees.  Live blocks are distinct physical ids;
+    a row's dead entries are the junk block or NaN blocks of their own."""
+    B, KV, G, hd = len(lengths), 2, 3, 32
+    NP = B * NBT + 1
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(B, KV, G, hd), jnp.float32)
+    kn = jnp.asarray(rng.randn(B, KV, 1, hd), jnp.float32)
+    vn = jnp.asarray(rng.randn(B, KV, 1, hd), jnp.float32)
+    tables = 1 + rng.permutation(B * NBT).reshape(B, NBT)
+    tables[-1, -(-lengths[-1] // BS):] = 0     # last row: dead -> junk
+    dead = np.zeros(NP, bool)
+    dead[0] = True
+    for b, n in enumerate(lengths):
+        dead[tables[b, -(-n // BS):]] = True
+    poison = jnp.asarray(dead)[:, None, None, None]
+    if int8:
+        pools = [quantize_int8(jnp.asarray(rng.randn(NP, BS, KV, hd),
+                                           jnp.float32), axis=-1)
+                 for _ in range(2)]
+        clean = (pools[0][0], pools[0][1], pools[1][0], pools[1][1])
+        bad = (clean[0], jnp.where(poison, jnp.nan, clean[1]),
+               clean[2], jnp.where(poison, jnp.nan, clean[3]))
+    else:
+        clean = tuple(jnp.asarray(rng.randn(NP, BS, KV, hd), jnp.bfloat16)
+                      for _ in range(2))
+        bad = tuple(jnp.where(poison, jnp.nan, c) for c in clean)
+    return (q, bad, clean, kn, vn, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(lengths, jnp.int32))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("BS,NBT,lengths", [
+    (8, 4, (8, 16, 24)),            # lengths exactly on a block boundary
+    (8, 4, (9, 17, 25)),            # one position past a boundary
+    (64, 6, (321, 130, 200)),       # NBT 6 over groups of 4 blocks
+    (32, 11, (352, 259, 0)),        # every block live beside length 0
+], ids=["boundary", "one_past", "ragged_groups", "full_and_empty"])
+def test_dead_blocks_are_never_read(BS, NBT, lengths, int8):
+    """NaN in every dead block must not reach the output: a kernel that
+    read one would leak it through ``0 * NaN`` in ``p @ v``."""
+    from repro.kernels.gqa_decode import group_blocks, paged_gqa_decode_int8
+    if BS == 64:                    # the last group is a partial one
+        assert NBT % group_blocks(BS, NBT)
+    q, bad, clean, kn, vn, tables, idx = _poisoned_problem(
+        BS * NBT, BS, NBT, list(lengths), int8)
+    if int8:
+        got = paged_gqa_decode_int8(q, bad[0], bad[1], bad[2], bad[3], kn,
+                                    vn, tables, idx, interpret=True)
+        want = ref.paged_decode_attention_int8_ref(q, *clean, kn, vn,
+                                                   tables, idx)
+    else:
+        got = paged_raw(q, *bad, kn, vn, tables, idx, interpret=True)
+        want = ref.paged_decode_attention_ref(
+            q, *(c.astype(jnp.float32) for c in clean), kn, vn, tables, idx)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_dead_table_entries_are_never_dereferenced(int8):
+    """Every table entry at or past ``cdiv(length, BS)`` points outside
+    the pool.  TPU interpret mode raises on an out-of-bounds DMA, so the
+    kernel passes only if it copies no dead block at all — the NaN test
+    above shows none reaches the output, this one that none is read."""
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels.gqa_decode import paged_gqa_decode_int8
+    BS, NBT, lengths = 8, 6, [9, 48, 0]
+    q, _, clean, kn, vn, tables, idx = _poisoned_problem(
+        3, BS, NBT, lengths, int8)
+    NP = clean[0].shape[0]
+    dead = np.arange(NBT)[None, :] >= -(-np.asarray(lengths)[:, None] // BS)
+    off_pool = jnp.where(jnp.asarray(dead), NP + 1000, tables)
+    try:
+        if int8:
+            got = paged_gqa_decode_int8(q, *clean, kn, vn, off_pool, idx,
+                                        interpret=pltpu.InterpretParams())
+            want = ref.paged_decode_attention_int8_ref(q, *clean, kn, vn,
+                                                       tables, idx)
+        else:
+            got = paged_raw(q, *clean, kn, vn, off_pool, idx,
+                            interpret=pltpu.InterpretParams())
+            want = ref.paged_decode_attention_ref(
+                q, *(c.astype(jnp.float32) for c in clean), kn, vn, tables,
+                idx)
+    finally:
+        pltpu.reset_tpu_interpret_mode_state()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
 
 
 # ----------------------------------------------------- property sweep

@@ -266,6 +266,41 @@ def test_early_stop_releases_capacity_to_queued_arrivals(cfg, sync_engine):
     assert st["admitted"] == 2 and st["released"] == 2
 
 
+# -------------------------------------------------------- decode span
+
+def test_decode_span_counts_live_blocks(cfg, sync_engine, tmp_path):
+    """``engine.decode`` carries ``blocks``: the pool blocks its rows'
+    positions fill, ``sum(cdiv(pos, block_size))`` — the blocks the
+    paged decode kernel walks, against ``rows * table width`` reserved."""
+    from jax.profiler import ProfileData
+    eng = ContinuousBatchingEngine(cfg, max_slots=3, max_seq=64,
+                                   params=sync_engine.params, paged=True,
+                                   block_size=16)
+    want, ensure = [], eng._ensure_decode_blocks
+
+    def noting():
+        ensure()
+        want.append(sum(-(-s.pos // 16)
+                        for s in eng.slots.active_slots()))
+
+    eng._ensure_decode_blocks = noting
+    rng = np.random.RandomState(3)
+    reqs = [GenerationRequest(rng.randint(0, cfg.vocab_size, size=n),
+                              max_new_tokens=k)
+            for n, k in ((5, 20), (16, 6), (30, 12))]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    got = [int(dict(e.stats)["blocks"])
+           for p in ProfileData.from_file(str(path)).planes
+           for line in p.lines for e in line.events
+           if e.name == "engine.decode"]
+    assert got == want and max(want) > len(reqs)
+
+
 # ------------------------------------------------------- runtime/compile
 
 def test_paged_decode_static_signature_no_bucket_misses(cfg, sync_engine):

@@ -6,7 +6,8 @@ catches what interpret mode cannot — block shapes the TPU lowering
 refuses, VMEM overuse, a program that does not fit the device.  Shapes:
 8 decode slots, 9 query / 3 kv heads, head_dim 64 with the pool
 lane-padded to 128, 32-position blocks, 2048-token context
-(``configs/smollm_135m.py``).
+(``configs/smollm_135m.py``); the paged decode also at yi-6b's widths
+over the benchmark's 512- and 4096-position tables.
 
 The topology is described inside a module fixture (the TPU library
 loads there, in the worker that runs this file, never at import time),
@@ -97,6 +98,34 @@ def test_paged_decode_compiles(one_chip, int8):
             q, k, v, kn, vn, t, i, kv_index=KV_INDEX, interpret=False)
         hlo = _compile(fn, q, pages, pages, tok, tok, tables, idx)
     assert "tpu_custom_call" in hlo
+    assert _kernel_names(hlo) == {
+        "paged_gqa_decode_int8" if int8 else "paged_gqa_decode"}
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("nbt", [16, 128], ids=["pool512", "pool4096"])
+def test_paged_decode_compiles_at_yi6b_widths(one_chip, nbt, int8):
+    """The paged decode at yi-6b's widths (32 slots, 32/4 heads,
+    head_dim 128, 32-position blocks) over a 512- and a 4096-position
+    table: the one-grid-step-per-row kernel's group buffers fit VMEM."""
+    yi = ARCHS["yi-6b"]
+    hp, kv, hd, b = yi.num_heads, yi.num_kv_heads, yi.resolved_head_dim, 32
+    S = lambda shape, dt=jnp.bfloat16: _spec(one_chip, shape, dt)
+    pages = S((b * nbt + 1, BS, kv, hd), jnp.int8 if int8 else jnp.bfloat16)
+    scales = S((b * nbt + 1, BS, kv, 1), jnp.float32)
+    q, tok = S((b, 1, hp, hd)), S((b, 1, kv, hd))
+    tables, idx = S((b, nbt), jnp.int32), S((b,), jnp.int32)
+    kv_index = tuple(h * kv // hp for h in range(hp))
+    if int8:
+        fn = lambda q, k, ks, v, vs, kn, vn, t, i: ops.paged_gqa_decode_int8(
+            q, k, ks, v, vs, kn, vn, t, i, kv_index=kv_index,
+            interpret=False)
+        hlo = _compile(fn, q, pages, scales, pages, scales, tok, tok,
+                       tables, idx)
+    else:
+        fn = lambda q, k, v, kn, vn, t, i: ops.paged_gqa_decode(
+            q, k, v, kn, vn, t, i, kv_index=kv_index, interpret=False)
+        hlo = _compile(fn, q, pages, pages, tok, tok, tables, idx)
     assert _kernel_names(hlo) == {
         "paged_gqa_decode_int8" if int8 else "paged_gqa_decode"}
 
