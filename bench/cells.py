@@ -1,26 +1,30 @@
 """Finds a cell's pieces by the names in ``BENCHMARK.json``: the
-configuration file, the traffic file (``bench/traffic/<traffic>.json``)
-and the metrics that apply to the cell."""
+configuration file and its architecture (``bench/arch/<model_type>.py``),
+the traffic file (``bench/traffic/<traffic>.json``) and the metrics that
+apply to the cell."""
 from __future__ import annotations
 
 import dataclasses
 import json
 from pathlib import Path
 
+from bench import arch
+
 ROOT = Path(__file__).resolve().parent.parent
 TRAFFIC_DIR = Path(__file__).resolve().parent / "traffic"
 
 
 def model_dims(c: dict) -> dict:
-    """Model dimensions from a configuration file (Hugging Face keys)."""
-    H = c["num_attention_heads"]
-    return {"d_model": c["hidden_size"], "num_layers": c["num_hidden_layers"],
-            "d_ff": c["intermediate_size"], "vocab_size": c["vocab_size"],
-            "num_heads": H, "num_kv_heads": c["num_key_value_heads"],
-            "head_dim": c.get("head_dim") or c["hidden_size"] // H,
-            "rope_theta": float(c["rope_theta"]),
-            "norm_eps": float(c["rms_norm_eps"]),
-            "tie_embeddings": bool(c["tie_word_embeddings"])}
+    """Model dimensions from a configuration file, by the module of its
+    ``model_type`` (``bench/arch``), with ``model_type`` among them."""
+    dims = dict(arch.load(c["model_type"]).dims(c),
+                model_type=c["model_type"])
+    try:
+        hash(tuple(sorted(dims.items())))
+    except TypeError:
+        raise TypeError(f"{c['model_type']} dims must all be hashable "
+                        f"(scalars, or tuples per layer): {dims}") from None
+    return dims
 
 
 @dataclasses.dataclass

@@ -1,4 +1,5 @@
-"""Operations and bytes a served token needs, from the model's shapes.
+"""Operations and bytes a served token needs, from the model's shapes,
+by the module of its architecture (``bench/arch``).
 
 Counts are of the work the algorithm requires, not of what a build
 happens to do: bucket padding, lane padding of the pool and recomputed
@@ -7,45 +8,55 @@ unless the time leaves out part of the work.
 """
 from __future__ import annotations
 
-
-def layer_params(m: dict) -> int:
-    """Weights of every layer's projections and MLP."""
-    d, L, ff = m["d_model"], m["num_layers"], m["d_ff"]
-    H, KV, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    return L * (d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * ff)
+from bench import arch
 
 
-def matmul_params(m: dict) -> int:
-    """Weights a generated token multiplies against: the layers plus the
-    output head (the embedding is a row lookup)."""
-    return layer_params(m) + m["d_model"] * m["vocab_size"]
+def _arch(m: dict):
+    return arch.load(m["model_type"])
 
 
 def token_flops(m: dict, context: int) -> float:
-    """FLOPs of one token at a position that attends ``context`` keys
-    (itself included): 2 per weight, plus QK^T and PV over the context."""
-    attn = 4 * m["num_layers"] * m["num_heads"] * m["head_dim"] * context
-    return 2.0 * matmul_params(m) + attn
+    """FLOPs of one generated token at a position that attends
+    ``context`` keys (itself included)."""
+    return _arch(m).token_flops(m, context)
 
 
 def prefill_chunk_flops(m: dict, offset: int, n: int) -> float:
-    """Tokens ``offset .. offset + n - 1`` of one prompt.  The output
-    head is not counted: a prompt token's logits are not needed, and the
-    one that samples the first token is a rounding error."""
-    L, H, hd = m["num_layers"], m["num_heads"], m["head_dim"]
-    # sum over positions p of (p + 1) attended keys
-    keys = n * offset + n * (n + 1) // 2
-    return 2.0 * layer_params(m) * n + 4.0 * L * H * hd * keys
+    """FLOPs of tokens ``offset .. offset + n - 1`` of one prompt."""
+    return _arch(m).prefill_chunk_flops(m, offset, n)
 
 
-def kv_bytes_per_token(m: dict, dtype_bytes: int = 2) -> int:
-    """K and V of one position over all layers, at the real head_dim."""
-    return 2 * m["num_layers"] * m["num_kv_heads"] * m["head_dim"] * dtype_bytes
+def attn_flops(m: dict, ctx: int) -> float:
+    """Attention FLOPs of one token at context ``ctx``."""
+    return _arch(m).attn_flops(m, ctx)
 
 
-def weight_bytes(m: dict, dtype_bytes: int = 2) -> int:
-    """Weights one decode step must read: every layer and the head
-    (norm weights are float32; the embedding rows read are negligible)."""
-    d, L = m["d_model"], m["num_layers"]
-    return matmul_params(m) * dtype_bytes + (2 * L + 1) * d * 4
+def kv_read_bytes(m: dict, ctx: int) -> int:
+    """K and V bytes a token reads at context ``ctx`` (with ``ctx`` 1:
+    the bytes one new position writes)."""
+    return _arch(m).kv_read_bytes(m, ctx)
 
+
+def step_weight_bytes(m: dict, rows) -> int:
+    """Weight bytes one decode step of ``rows`` rows must read."""
+    return _arch(m).step_weight_bytes(m, rows)
+
+
+def layer_params(m: dict) -> int:
+    """Weights of every layer's projections and MLP."""
+    return _arch(m).layer_params(m)
+
+
+def matmul_params(m: dict) -> int:
+    """Weights a generated token multiplies against."""
+    return _arch(m).matmul_params(m)
+
+
+def kv_bytes_per_token(m: dict) -> int:
+    """K and V of one position over all layers."""
+    return _arch(m).kv_bytes_per_token(m)
+
+
+def weight_bytes(m: dict) -> int:
+    """Every weight a decode step can read, in the served types."""
+    return _arch(m).weight_bytes(m)

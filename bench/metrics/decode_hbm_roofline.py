@@ -32,12 +32,14 @@ def read(obs):
                 continue                  # token 0 comes from the prefill
             ctx = P + i - 1               # cached positions the step read
             rows += 1
-            kv += ctx
+            kv += flops.kv_read_bytes(m, ctx)
             fl += flops.token_flops(m, ctx + 1)
     if rows == 0:
         return None
-    byts = steps * flops.weight_bytes(m) + flops.kv_bytes_per_token(m) * (
-        kv + rows)
+    # a step's weights at the span's mean rows a step; each row writes
+    # one new position
+    byts = steps * flops.step_weight_bytes(m, rows / steps) + (
+        kv + rows * flops.kv_read_bytes(m, 1))
     pk = obs.peaks
     least = max(byts / pk["hbm_bytes_per_s"], fl / pk["bf16_flops"])
     return 100.0 * least / dev_s

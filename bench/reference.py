@@ -1,10 +1,11 @@
-"""Plain reference forward of a Llama-architecture decoder.
+"""Plain reference forward of the served model.
 
-Written from the published architecture (RMSNorm, rotary embeddings on
-the two halves of each head, grouped-query attention, SwiGLU MLP) in
-float32 at the highest matmul precision, with no cache, kernel or
-batching.  It imports nothing of the program under test and reads only
-the weights that ``bench/weights.py`` made.
+The architecture's own forward (``bench/arch/<model_type>.py``, e.g. a
+Llama decoder: RMSNorm, rotary embeddings, grouped-query attention,
+SwiGLU), run here over one sequence in float32 at the highest matmul
+precision, with no cache, kernel or batching.  It imports nothing of the
+program under test and reads only the weights that ``bench/weights.py``
+made.
 
 ``precision="fp8"`` is the control: the same forward with every matmul
 operand (weights and activations) rounded to float8 e4m3 — the
@@ -17,6 +18,8 @@ import functools
 
 import numpy as np
 
+from bench import arch
+
 PRECISIONS = ("float32", "fp8")
 
 
@@ -28,22 +31,6 @@ def _round(x, precision):
     return x
 
 
-def _rms(x, w, eps):
-    import jax
-    import jax.numpy as jnp
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
-
-
-def _rope(x, pos, theta):
-    import jax.numpy as jnp
-    hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = pos[:, None].astype(jnp.float32) * freqs            # (S, hd/2)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., : hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
 @functools.lru_cache(maxsize=None)
 def _forward_fn(dims: tuple, precision: str, n_out: int):
     """Jitted forward for one (shape set, precision, output rows): the
@@ -52,42 +39,12 @@ def _forward_fn(dims: tuple, precision: str, n_out: int):
     import jax
     import jax.numpy as jnp
     m = dict(dims)
-    H, KV, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    eps, theta = m["norm_eps"], m["rope_theta"]
-    group = H // KV
     r = functools.partial(_round, precision=precision)
 
     def mm(spec, a, b):
         return jnp.einsum(spec, r(a), r(b))
 
-    def layer(x, lp):
-        S = x.shape[0]
-        pos = jnp.arange(S)
-        h = _rms(x, lp["ln1"].astype(jnp.float32), eps)
-        a = lp["attn"]
-        q = _rope(mm("sd,dhk->shk", h, a["wq"]), pos, theta)
-        k = _rope(mm("sd,dhk->shk", h, a["wk"]), pos, theta)
-        v = mm("sd,dhk->shk", h, a["wv"])
-        k = jnp.repeat(k, group, axis=1)                      # (S, H, hd)
-        v = jnp.repeat(v, group, axis=1)
-        s = mm("qhk,shk->hqs", q, k) / np.sqrt(hd)
-        causal = jnp.tril(jnp.ones((S, S), bool))
-        p = jax.nn.softmax(jnp.where(causal[None], s, -jnp.inf), axis=-1)
-        o = mm("hqs,shk->qhk", p, v)
-        x = x + mm("qhk,hkd->qd", o, a["wo"])
-        h = _rms(x, lp["ln2"].astype(jnp.float32), eps)
-        f = lp["mlp"]
-        g = mm("sd,df->sf", h, f["w_gate"])
-        u = mm("sd,df->sf", h, f["w_up"])
-        return x + mm("sf,fd->sd", jax.nn.silu(g) * u, f["w_down"]), None
-
-    def fwd(params, tokens, rows):
-        x = params["embed"]["table"][tokens].astype(jnp.float32)
-        x, _ = jax.lax.scan(layer, x, params["layers"])
-        x = _rms(x[rows], params["final_norm"].astype(jnp.float32), eps)
-        w = (params["embed"]["table"].T if m["tie_embeddings"]
-             else params["head"]["w"])
-        return mm("sd,dv->sv", x, w)
+    fwd = arch.load(m["model_type"]).forward(m, mm)
 
     def run(params, tokens, rows):
         with jax.default_matmul_precision("highest"):

@@ -24,6 +24,7 @@ from typing import Optional
 
 import numpy as np
 
+from bench import arch
 from bench import traffic as traffic_lib
 
 # open-loop requests due in the window may finish this long after it
@@ -36,16 +37,9 @@ def log(msg: str) -> None:
 
 
 def model_config(name: str, dims: dict):
-    """The program's ``ModelConfig`` for a configuration's dimensions."""
-    from repro.configs.model_config import ModelConfig
-    return ModelConfig(
-        name=name, family="dense", num_layers=dims["num_layers"],
-        d_model=dims["d_model"], num_heads=dims["num_heads"],
-        num_kv_heads=dims["num_kv_heads"], d_ff=dims["d_ff"],
-        vocab_size=dims["vocab_size"], head_dim=dims["head_dim"],
-        mlp_type="swiglu", dtype="bfloat16", kv_cache_dtype="bfloat16",
-        rope_theta=float(dims["rope_theta"]), norm_eps=dims["norm_eps"],
-        tie_embeddings=dims["tie_embeddings"])
+    """The program's configuration for a configuration's dimensions, by
+    the module of its architecture (``bench/arch``)."""
+    return arch.load(dims["model_type"]).program_config(name, dims)
 
 
 @dataclasses.dataclass

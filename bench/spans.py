@@ -8,16 +8,15 @@ on the same clock as the device planes, one line per thread.
 ``program_spans`` reads them from a trace; the helpers below are what
 the span readers (``bench/metrics/loop_host_ms.py``,
 ``dispatch_host_ms.py``, ``decode_attn_hbm_roofline.py``) compute, from
-``obs.trace.spans`` (section 7 of ``PERF.md`` says what the harness
-still lacks to fill it).
+``obs.trace.spans`` (``bench/trace.reduce`` fills it).
 
 Run as a script on the chip, it serves a cell with a traced span, as
 ``bench/run.py --trace 1`` does, once per seed in one process:
 
     python3 bench/spans.py --workload <cell> --seeds 1,2,3 --seconds 51
 
-and prints one JSON line per seed: the cell's per-layer metrics and the
-span readers' values, the device's idle time in the span by the
+and prints one JSON line per seed: the cell's per-layer metrics (the
+span readers among them), the device's idle time in the span by the
 innermost program span (and host event) over each idle gap, the clock
 check of the traced span, and the decode call's host ms inside the
 traced span against outside it (what tracing costs).
@@ -37,9 +36,6 @@ from bench import trace  # noqa: E402
 
 PREFIXES = ("engine.", "runtime.")
 WAIT = "runtime.wait"
-# the span readers and their units
-READERS = {"loop_host_ms": "ms", "dispatch_host_ms": "ms",
-           "decode_attn_hbm_roofline": "%"}
 
 
 class Span(NamedTuple):
@@ -79,10 +75,11 @@ def inside(inner: Span, outer: Span) -> bool:
 
 
 def traced(trace, name: str) -> list[Span]:
-    """Spans ``name`` that start in the traced span of a Trace that
-    carries ``spans`` (its window starts at 0); none without a trace."""
-    spans = getattr(trace, "spans", None) or ()
-    return [s for s in spans
+    """Spans ``name`` that start in the traced span of a Trace (its
+    window starts at 0); none without a trace."""
+    if trace is None:
+        return []
+    return [s for s in trace.spans
             if s.name == name and 0.0 <= s.start_s < trace.window_s]
 
 
@@ -178,8 +175,7 @@ def idle_by_span(path: str, window: tuple[float, float],
 
 def _capture() -> dict:
     """Keep what ``run.run_cell`` reduces and throws away: the traced
-    run's program spans (set on its Trace, where the span readers find
-    them), the idle time by span, and the measured window."""
+    run's Trace, the idle time by span, and the measured window."""
     from bench import serve
     got: dict = {}
     reduce, runners = trace.reduce, dict(serve.RUNNERS)
@@ -187,7 +183,6 @@ def _capture() -> dict:
     def reduce_keeping(path, window, **kw):
         tr = reduce(path, window, **kw)
         if tr is not None:
-            tr.spans = program_spans(path, window)
             got["idle"] = idle_by_span(path, window, tr.spans)
             got["trace"] = tr
         return tr
@@ -253,8 +248,6 @@ def main(argv=None) -> int:
         print(f"spans: {err}", file=sys.stderr)
         return 2
     run.enable_cache()
-    cell.per_layer = cell.per_layer + [{"name": k, "unit": u}
-                                       for k, u in READERS.items()]
     got = _capture()
     for seed in (int(s) for s in args.seeds.split(",")):
         got.clear()

@@ -1,7 +1,7 @@
 """Reduction of a ``jax.profiler`` trace (``.xplane.pb``) to the numbers
 the per-layer metrics read: device busy time, device time per program,
-the top device operations and the longest idle gaps, each named by what
-the host was doing meanwhile.
+the top device operations, the longest idle gaps, each named by what
+the host was doing meanwhile, and the program's own spans.
 
 Device planes are ``/device:TPU:<n>``.  Busy time is the union of the
 intervals of the plane's op line (``XLA Ops``; the module line when a
@@ -64,6 +64,8 @@ class Trace:
     programs: dict                   # program name -> [seconds, count], summed
     ops: dict                        # op name -> seconds, summed
     idle_gaps: list                  # [(host activity, seconds)], longest first
+    # the program's spans over the window (bench/spans.program_spans)
+    spans: list = dataclasses.field(default_factory=list)
 
     @property
     def busy_mean_s(self) -> float:
@@ -146,7 +148,9 @@ def reduce(path: str, window: tuple[float, float],
     host = _host_events(planes, lo, hi)
     named = sorted(((_doing(host, s, e), e - s) for s, e in first_gaps or ()),
                    key=lambda kv: -kv[1])
-    return Trace(len(dev), hi - lo, busy, programs, ops, named)
+    from bench import spans         # which imports this module
+    return Trace(len(dev), hi - lo, busy, programs, ops, named,
+                 spans.program_spans(path, window))
 
 
 def _host_events(planes, lo, hi) -> list[tuple[float, float, str]]:

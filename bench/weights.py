@@ -1,16 +1,15 @@
 """Seeded random weights, made on the device in one jitted call.
 
-The tree is laid out the way the serving engine takes its parameters
-(stacked layers, ``(d, heads, head_dim)`` projections, float32 norm
-weights) and is served in bfloat16.  The benchmark makes it; the
-program only receives it, and the plain reference in
-``bench/reference.py`` reads the same arrays.
+The tree is the architecture's ``shapes`` (``bench/arch``), laid out the
+way the serving engine takes its parameters, and is served in bfloat16.
+The benchmark makes it; the program only receives it, and the plain
+reference in ``bench/reference.py`` reads the same arrays.
 """
 from __future__ import annotations
 
-import math
-
 import numpy as np
+
+from bench import arch
 
 # norm weights are 1 + NORM_NOISE * N(0, 1), so a reference that ignored
 # them, or applied them to the wrong side, would disagree
@@ -32,28 +31,9 @@ def jax_key(seed: int, stream: int = 0):
 
 
 def shapes(m: dict) -> dict:
-    """(shape, dtype, init std or 'norm') for every leaf of the tree."""
-    d, L, ff, V = m["d_model"], m["num_layers"], m["d_ff"], m["vocab_size"]
-    H, KV, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    bf, f32 = "bfloat16", "float32"
-    tree = {
-        "embed": {"table": ((V, d), bf, EMBED_STD)},
-        "layers": {
-            "attn": {"wq": ((L, d, H, hd), bf, 1 / math.sqrt(d)),
-                     "wk": ((L, d, KV, hd), bf, 1 / math.sqrt(d)),
-                     "wv": ((L, d, KV, hd), bf, 1 / math.sqrt(d)),
-                     "wo": ((L, H, hd, d), bf, 1 / math.sqrt(H * hd))},
-            "mlp": {"w_gate": ((L, d, ff), bf, 1 / math.sqrt(d)),
-                    "w_up": ((L, d, ff), bf, 1 / math.sqrt(d)),
-                    "w_down": ((L, ff, d), bf, 1 / math.sqrt(ff))},
-            "ln1": ((L, d), f32, "norm"),
-            "ln2": ((L, d), f32, "norm"),
-        },
-        "final_norm": ((d,), f32, "norm"),
-        "head": ({} if m["tie_embeddings"]
-                 else {"w": ((d, V), bf, 1 / math.sqrt(d))}),
-    }
-    return tree
+    """(shape, dtype, init std or 'norm') for every leaf of the tree, by
+    the module of the architecture (``bench/arch``)."""
+    return arch.load(m["model_type"]).shapes(m)
 
 
 def _is_leaf(x) -> bool:

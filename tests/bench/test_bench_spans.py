@@ -176,7 +176,7 @@ def test_span_readers_find_nothing(case):
     sp = _step(0.1, 300)
     if case == "no_trace":
         tr = None
-    elif case == "no_spans":            # the harness's Trace as it is
+    elif case == "no_spans":            # a Trace made with none
         tr = trace.Trace(devices=1, window_s=1.0, busy_s=[0.8], programs={},
                          ops={"paged_gqa_decode": 0.004}, idle_gaps=[])
     elif case == "empty_spans":

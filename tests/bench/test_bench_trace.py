@@ -1,5 +1,7 @@
 """The trace reduction, on hand-made intervals and on a trace recorded
 on the CPU (which has no device plane)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -41,3 +43,23 @@ def test_cpu_trace_has_no_device_plane(tmp_path):
     assert path is not None
     assert trace.reduce(path, (0.0, 1.0)) is None
     assert "/host:CPU" in trace.describe(path)
+
+
+def test_reduce_hands_over_the_program_spans(tmp_path):
+    """``reduce`` keeps the program's spans on the Trace it returns (a CPU
+    trace's host plane stands in for a device plane)."""
+    f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((64, 64))
+    f(x).block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("engine.iteration", queued=1,
+                                          active=2):
+            f(x).block_until_ready()
+        with jax.profiler.TraceAnnotation("other.span"):
+            pass
+    path = trace.find_xplane(str(tmp_path))
+    tr = trace.reduce(path, (0.0, 1e9),
+                      device_plane=re.compile(r"^/host:CPU$"))
+    assert [(s.name, s.args) for s in tr.spans] == [
+        ("engine.iteration", {"queued": 1, "active": 2})]
+    assert trace.Trace(1, 1.0, [0.5], {}, {}, []).spans == []
